@@ -18,6 +18,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (kernel_bench, table2_fft, table3_power,
                             table4_fir, table5_app)
 

@@ -8,6 +8,7 @@ Run:  PYTHONPATH=src python examples/serve_lm.py
 """
 import time
 
+import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
@@ -38,8 +39,9 @@ dt = time.perf_counter() - t0
 for r in sorted(done, key=lambda r: r.rid):
     print(f"req {r.rid}: {r.prompt} -> {r.out}")
 tok = sum(len(r.out) for r in done)
+dev = jax.devices()[0]
 print(f"{len(done)} requests, {tok} tokens in {dt:.1f}s "
-      f"({tok / dt:.1f} tok/s, CPU)")
+      f"({tok / dt:.1f} tok/s, {dev.platform} {dev.device_kind})")
 assert len(done) == 6 and all(len(r.out) == 12 for r in done)
 
 # same traffic, supervised, with slot 0 killed at its 4th dispatch

@@ -22,9 +22,11 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.fft import rfft_packed
 from repro.core.fir import fir_direct, lowpass_taps
+from repro.core.shuffle import compact_even
 
 
 # ---------------------------------------------------------------------------
@@ -73,9 +75,10 @@ def delineate(x, *, min_prominence: float = 0.3, min_distance: int = 15):
     if min_distance > 0:
         is_max &= x >= _dilate(x, jnp.maximum, min_distance)
         is_min &= x <= _dilate(x, jnp.minimum, min_distance)
-    # edges are never extrema
-    edge = jnp.zeros_like(is_max).at[..., 0].set(True).at[..., -1].set(True)
-    return is_max & ~edge, is_min & ~edge
+    # edges are never extrema (iota compares, not a scatter: Mosaic-safe)
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    inner = (pos > 0) & (pos < x.shape[-1] - 1)
+    return is_max & inner, is_min & inner
 
 
 def _masked_intervals_sort(mask):
@@ -101,128 +104,84 @@ def _masked_intervals_sort(mask):
     return mean, med, rms
 
 
-@functools.lru_cache(maxsize=None)
-def oddeven_tables(n: int) -> tuple:
-    """Stage tables of Batcher's odd-even merge sort for a power-of-two
-    length `n`: (lo, hi, ks) numpy arrays of shape (n_stages, n) x2 and
-    (n_stages, 1). Stage s compare-exchanges the disjoint pairs
-    (t, t + ks[s]): a slot with lo[s, t] keeps min(x[t], x[t+k]), a slot
-    with hi[s, t] keeps max(x[t], x[t-k]). Classic Batcher pairing — t in
-    the upper-k half of its 2k-group (offset by k%p), both endpoints in
-    the same 2p-block.
-
-    The tables are STAGED OPERANDS of the fused kernel (like the FFT
-    twiddle tables — the paper keeps such tables in the SPM): Pallas
-    kernels cannot capture array constants, and recomputing the masks
-    every `fori_loop` iteration doubles the per-stage op count."""
-    assert n >= 1 and n & (n - 1) == 0, n
-    t = np.arange(n)
-    los, his, ks = [], [], []
-    p = 1
-    while p < n:
-        k = p
-        while k >= 1:
-            lo = (((t - (k % p)) % (2 * k)) < k) & (t + k < n) & \
-                ((t // (2 * p)) == ((t + k) // (2 * p)))
-            los.append(lo)
-            his.append(np.roll(lo, k))   # lo slots >= n-k are False: no wrap
-            ks.append(k)
-            k //= 2
-        p *= 2
-    if not los:                          # n == 1: the empty network
-        return (np.zeros((0, n), bool), np.zeros((0, n), bool),
-                np.zeros((0, 1), np.int32))
-    return (np.stack(los), np.stack(his),
-            np.asarray(ks, np.int32).reshape(-1, 1))
-
-
-def network_sort(x, tables=None):
+def network_sort(x):
     """Ascending sort along the last (power-of-two) axis via Batcher's
-    odd-even merge network: O(log^2 n) vectorized stages of shift +
-    select, driven by the `oddeven_tables` stage masks. No `sort`,
-    `take_along_axis`, or gather — shifts, compares and selects only,
-    closing the fused kernel's Mosaic-compile gap. `tables` lets a Pallas
-    caller pass the masks as staged kernel operands."""
+    odd-even merge network: O(log^2 n) vectorized stages of rotate +
+    compare + select. Stage (p = 2^a, k) compare-exchanges the disjoint
+    pairs (t, t + k): a "lo" slot keeps min(x[t], x[t+k]), a "hi" slot
+    keeps max(x[t], x[t-k]) — classic Batcher pairing, t in the upper-k
+    half of its 2k-group (offset by k % p), both endpoints in the same
+    2p-block. The masks are iota arithmetic, so the network needs no
+    table operand, and the traced shifts are `pltpu.roll` lane rotates
+    (a traced `jnp.roll` is a dynamic slice, which Mosaic cannot lower;
+    outside a kernel `pltpu.roll` lowers to `jnp.roll`): it runs under
+    Mosaic and XLA alike."""
     n = x.shape[-1]
     assert n & (n - 1) == 0, f"network_sort needs a power-of-two length: {n}"
-    lo_t, hi_t, k_t = tables if tables is not None else tuple(
-        jnp.asarray(a) for a in oddeven_tables(n))
-    n_stages = lo_t.shape[0]
-    if n_stages == 0:                # n == 1: the empty network
-        return x
+    ax = x.ndim - 1
+    t = jax.lax.broadcasted_iota(jnp.int32, x.shape, ax)
 
-    def stage(s, y):
-        k = k_t[s, 0]
-        lo = jax.lax.dynamic_slice_in_dim(lo_t, s, 1, 0)[0]
-        hi = jax.lax.dynamic_slice_in_dim(hi_t, s, 1, 0)[0]
-        z = jnp.concatenate([y, y], axis=-1)          # one buffer, two views
-        fwd = jax.lax.dynamic_slice_in_dim(z, k, n, z.ndim - 1)
-        bwd = jax.lax.dynamic_slice_in_dim(z, n - k, n, z.ndim - 1)
+    def stage(a, j, y):
+        k = jnp.left_shift(jnp.int32(1), a - j)
+        kmodp = jnp.where(j == 0, 0, k)      # k % p: k == p exactly at j == 0
+        blk = t >> (a + 1)
+        lo = (((t - kmodp) & (2 * k - 1)) < k) & (t + k < n) & \
+            (blk == ((t + k) >> (a + 1)))
+        u = t - k                            # hi[t] == lo[t - k]
+        hi = (u >= 0) & (((u - kmodp) & (2 * k - 1)) < k) & \
+            ((u >> (a + 1)) == blk)
+        fwd = pltpu.roll(y, n - k, ax)       # y[t + k]
+        bwd = pltpu.roll(y, k, ax)           # y[t - k]
         return jnp.where(lo, jnp.minimum(y, fwd),
                          jnp.where(hi, jnp.maximum(y, bwd), y))
 
-    # NOTE: keep the loop rolled — XLA CPU pessimizes any unrolling of this
-    # body (unroll=4 measured 3x slower, full unroll 60x slower)
-    return jax.lax.fori_loop(0, n_stages, stage, x)
-
-
-def _network_sort_arith(x):
-    """`network_sort` with the stage masks recomputed from iota arithmetic
-    each iteration instead of read from tables. Slower (≈2x), but capture-
-    free: this is the exact-fallback path inside Pallas kernels, where the
-    fixed-size stage tables are sized for `INTERVAL_SLOTS` and a full-
-    length sort has no table operand to read."""
-    n = x.shape[-1]
-    assert n & (n - 1) == 0, n
-    t = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-
-    def stage(x, a, j):
-        k = jnp.left_shift(1, a - j)
-        kmodp = jnp.where(j == 0, 0, k)      # k % p: k == p exactly at j == 0
-        lo = (((t - kmodp) & (2 * k - 1)) < k) & (t + k < n) & \
-            ((t >> (a + 1)) == ((t + k) >> (a + 1)))
-        hi = jnp.roll(lo, k)
-        fwd = jnp.roll(x, -k, axis=-1)
-        bwd = jnp.roll(x, k, axis=-1)
-        return jnp.where(lo, jnp.minimum(x, fwd),
-                         jnp.where(hi, jnp.maximum(x, bwd), x))
-
-    def outer(a, y):                  # p = 2^a; inner: k = p, p/2, ..., 1
-        return jax.lax.fori_loop(
-            0, a + 1, lambda j, z: stage(z, a, j), y)
-
-    return jax.lax.fori_loop(0, max(n.bit_length() - 1, 0), outer, x)
+    for a in range(n.bit_length() - 1):      # p = 2^a; k = p, p/2, ..., 1
+        x = jax.lax.fori_loop(0, a + 1, functools.partial(stage, a), x)
+    return x
 
 
 def _interval_gaps(mask):
     """Gaps between consecutive True positions as mask algebra: a running
-    cummax of the last-seen True index replaces the seed's compaction sort.
+    max of the last-seen True index replaces the seed's compaction sort.
     Returns (gaps, valid) full-window arrays — position i carries the gap
     to its predecessor extremum iff valid[i]."""
     S = mask.shape[-1]
-    pos = jnp.arange(S, dtype=jnp.int32)
-    prev = jax.lax.cummax(jnp.where(mask, pos, -1), axis=mask.ndim - 1)
-    prev_excl = jnp.concatenate(
-        [jnp.full(mask.shape[:-1] + (1,), -1, prev.dtype), prev[..., :-1]],
-        axis=-1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, mask.shape, mask.ndim - 1)
+
+    def shift_in(v, s):                  # v[t - s], -1 filled from the left
+        return jnp.concatenate(
+            [jnp.full(mask.shape[:-1] + (s,), -1, v.dtype), v[..., :-s]],
+            axis=-1)
+
+    # running max in log2(S) shift steps (Mosaic has no cummax lowering)
+    prev = jnp.where(mask, pos, -1)
+    s = 1
+    while s < S:
+        prev = jnp.maximum(prev, shift_in(prev, s))
+        s *= 2
+    prev_excl = shift_in(prev, 1)
     valid = mask & (prev_excl >= 0)
     gaps = jnp.where(valid, pos - prev_excl, 0)
     return gaps, valid
 
 
-def _masked_intervals(mask, *, sparse2: bool = False, sort_tables=None):
+def _masked_intervals(mask, *, sparse2: bool = False):
     """Mean/median/RMS of gaps between consecutive True positions (masked
     statistics, fixed shapes — jit-friendly).
 
-    Mosaic-compilable formulation: gap extraction is cummax mask algebra
-    (`_interval_gaps`), the median is `network_sort` + a one-hot k-th-order
-    pick. Matches `_masked_intervals_sort` exactly — gap values are small
-    integers, so the f32 reductions are order-independent.
+    Mosaic-compilable formulation: gap extraction is running-max mask
+    algebra (`_interval_gaps`), the median is `network_sort` + a one-hot
+    k-th-order pick, and every fold is rotate + select. Matches
+    `_masked_intervals_sort` exactly — gap values are small integers, so
+    the f32 reductions are order-independent.
 
-    ``sparse2`` promises no two ADJACENT positions are both True (always
-    the case for `delineate` extrema: a strict rise cannot follow itself),
-    letting the median pre-fold even/odd slots so the network runs at half
-    the window length."""
+    The median network runs on the fixed `INTERVAL_SLOTS` buffer: the gap
+    array (sentinel-padded) is folded by min over segments of G slots.
+    That is exact whenever no segment holds two gaps; any segment that
+    does routes the whole batch to a full-length network (rare, slower,
+    always exact). ``sparse2`` promises no two ADJACENT positions are both
+    True (always the case for `delineate` extrema: a strict rise cannot
+    follow itself), which halves the slots the fold has to cover."""
     S = mask.shape[-1]
     gaps, valid = _interval_gaps(mask)
     nv = jnp.sum(valid, axis=-1)
@@ -230,70 +189,50 @@ def _masked_intervals(mask, *, sparse2: bool = False, sort_tables=None):
     g = jnp.where(valid, gaps, 0).astype(jnp.float32)
     mean = jnp.sum(g, axis=-1) / n
     rms = jnp.sqrt(jnp.sum(jnp.square(g), axis=-1) / n)
-    # gaps are in [0, S] — sort in the narrowest int the window allows to
-    # halve the bytes the network moves
-    sdt = jnp.int16 if S <= 2 ** 14 else jnp.int32
-    big = jnp.iinfo(sdt).max
-    vals = jnp.where(valid, gaps, big).astype(sdt)
-    k = ((n - 1) // 2)[..., None].astype(jnp.int32)
+    big = jnp.iinfo(jnp.int32).max
+    vals = jnp.where(valid, gaps, big)
+    k = ((n - 1) // 2)[..., None]
+    K = INTERVAL_SLOTS
 
     def kth_smallest(svals):
         sel = jax.lax.broadcasted_iota(jnp.int32, svals.shape,
                                        svals.ndim - 1)
-        return jnp.sum(jnp.where(sel == k, svals.astype(jnp.int32), 0),
-                       axis=-1)
+        return jnp.sum(jnp.where(sel == k, svals, 0), axis=-1)
 
-    def pad_pow2(v, to=0):
-        L = v.shape[-1]
-        N = max(1 << max(L - 1, 0).bit_length(), to)
-        if N == L:
+    def pad(v, width):
+        if width == S:
             return v
         return jnp.concatenate(
-            [v, jnp.full(mask.shape[:-1] + (N - L,), big, sdt)], axis=-1)
+            [v, jnp.full(mask.shape[:-1] + (width - S,), big, v.dtype)],
+            axis=-1)
 
-    collide = None                     # lossy-fold guard (traced bool)
-    folded = vals
-    if sparse2 and S % 2 == 0:
-        # each even/odd slot pair SHOULD hold at most one valid gap
-        # (guaranteed for delineate extrema, which are never adjacent) —
-        # fold to S/2, but GUARD it: sparse2 is a caller promise, not a
-        # property of the mask argument
-        ev, od = vals[..., 0::2], vals[..., 1::2]
-        folded = jnp.minimum(ev, od)
-        collide = jnp.any((ev < big) & (od < big))
-    folded = pad_pow2(folded, INTERVAL_SLOTS)
-    K = INTERVAL_SLOTS
-    if folded.shape[-1] > K:
-        # compact into the fixed K-slot buffer: fold segments of N/K
-        # slots by min. Exact whenever every segment holds at most one
-        # interval (sentinels are +inf) — true for any physiological
-        # signal, where extrema sit far apart. A colliding segment
-        # anywhere joins the guard below.
-        N = folded.shape[-1]
-        seg = jnp.sum((folded < big).reshape(mask.shape[:-1] + (K, N // K)),
-                      axis=-1)
-        seg_collide = jnp.any(seg > 1)
-        collide = seg_collide if collide is None else collide | seg_collide
-        y = folded
-        while y.shape[-1] > K:
-            y = jnp.minimum(y[..., 0::2], y[..., 1::2])
-        folded = y
+    def pow2(m):
+        return 1 << max(m - 1, 0).bit_length()
 
-    def fast(_):
-        return kth_smallest(network_sort(folded, tables=sort_tables))
-
-    if collide is None:
-        # no lossy fold happened: the fixed-size network is always exact
-        med = fast(None)
+    pre = 2 if sparse2 and S % 2 == 0 else 1
+    width = pre * max(pow2(S // pre), K)
+    seg = width // K                       # slots folded into one
+    buf = pad(vals, width)
+    if seg == 1:
+        # no lossy fold: the fixed-size network is always exact
+        med = kth_smallest(network_sort(buf))
     else:
-        # any collision routes the whole batch to a full-length network
-        # over the UNFOLDED gaps (rare, slower, always exact)
-        full = pad_pow2(vals)
-
-        def slow(_):
-            return kth_smallest(_network_sort_arith(full))
-
-        med = jax.lax.cond(collide, slow, fast, None)
+        # a segment holds two gaps iff its windowed count exceeds 1
+        cnt = (buf < big).astype(jnp.int32)
+        folded = buf
+        step = 1
+        while step < seg:
+            cnt = cnt + jnp.roll(cnt, -step, axis=-1)
+            folded = compact_even(jnp.minimum(folded,
+                                              jnp.roll(folded, -1, axis=-1)))
+            step *= 2
+        pos = jax.lax.broadcasted_iota(jnp.int32, cnt.shape, cnt.ndim - 1)
+        collide = jnp.any((cnt > 1) & ((pos & (seg - 1)) == 0))
+        folded = folded[..., :K]
+        full = pad(vals, pow2(S))
+        med = jax.lax.cond(collide,
+                           lambda: kth_smallest(network_sort(full)),
+                           lambda: kth_smallest(network_sort(folded)))
     med = jnp.where(nv > 0, med, 0).astype(jnp.float32)
     return mean, med, rms
 
@@ -311,25 +250,52 @@ def _masked_intervals(mask, *, sparse2: bool = False, sort_tables=None):
 INTERVAL_SLOTS = 128
 
 
-def interval_time_features(is_max, is_min, sort_tables=None) -> list:
+def interval_time_features(is_max, is_min) -> list:
     """The 6 time features: mean/median/RMS of the inspiration and
     expiration interval lengths (single source — also run inside the fused
     pipeline kernel). Both masks ride ONE sorting-network pass (stacked
     along the batch axis), and extrema are never adjacent, so the median
-    network runs at half the window length (`sparse2`). ``sort_tables``
-    forwards staged `oddeven_tables` operands from a Pallas caller."""
+    fold covers half the window (`sparse2`)."""
     if is_max.ndim >= 2:
         both = jnp.concatenate([is_max, is_min], axis=0)
-        mean, med, rms = _masked_intervals(both, sparse2=True,
-                                           sort_tables=sort_tables)
+        mean, med, rms = _masked_intervals(both, sparse2=True)
         R = is_max.shape[0]
         return [mean[:R], med[:R], rms[:R], mean[R:], med[R:], rms[R:]]
     f_time = []
     for mask in (is_max, is_min):
-        mean, med, rms = _masked_intervals(mask, sparse2=True,
-                                           sort_tables=sort_tables)
+        mean, med, rms = _masked_intervals(mask, sparse2=True)
         f_time += [mean, med, rms]
     return f_time
+
+
+# Cephes logf: log(1 + m) = m - m^2/2 + m^3 P(m) on [sqrt(1/2) - 1, sqrt(2) - 1]
+_LOG_POLY = (7.0376836292e-2, -1.1514610310e-1, 1.1676998740e-1,
+             -1.2420140846e-1, 1.4249322787e-1, -1.6668057665e-1,
+             2.0000714765e-1, -2.4999993993e-1, 3.3333331174e-1)
+
+
+def log1p(x):
+    """log(1 + x) for f32 x >= 0, to an ulp or two, from adds, multiplies
+    and bit operations only. The fused kernels take their logs here:
+    Mosaic's own log1p is off by up to 2.6e-4 relative on TPU v5e, which
+    a power spectrum's log reads as noise.
+
+    1 + x = (1 + f) 2^e with 1 + f in [sqrt(1/2), sqrt(2)): e comes from
+    the exponent bits of fl(1 + x), and f = (2^-e - 1) + x 2^-e is formed
+    from exact terms, so the low bits of x that fl(1 + x) drops are kept."""
+    bits = jax.lax.bitcast_convert_type(1.0 + x, jnp.int32)
+    e = (bits >> 23) - 127                           # fl(1 + x) in [2^e, 2^e+1)
+    mant = jax.lax.bitcast_convert_type((bits & 0x007FFFFF) | 0x3F800000,
+                                        jnp.float32)  # in [1, 2)
+    e = jnp.where(mant > 1.41421356, e + 1, e)
+    scale = jax.lax.bitcast_convert_type((127 - e) << 23, jnp.float32)
+    f = (scale - 1.0) + x * scale                    # 2^-e (1 + x) - 1
+    z = f * f
+    p = _LOG_POLY[0]
+    for c in _LOG_POLY[1:]:
+        p = p * f + c
+    ef = e.astype(jnp.float32)
+    return f + (f * z * p - 2.12194440e-4 * ef - 0.5 * z) + 0.693359375 * ef
 
 
 def band_power_features(power, fft_size: int) -> list:
@@ -337,7 +303,7 @@ def band_power_features(power, fft_size: int) -> list:
     source — also run inside the fused pipeline kernel)."""
     nb = fft_size // 2 + 1
     bands = np.linspace(1, nb, 7, dtype=int)         # 6 log-ish bands
-    return [jnp.log1p(jnp.sum(power[..., a:b], axis=-1))
+    return [log1p(jnp.sum(power[..., a:b], axis=-1))
             for a, b in zip(bands[:-1], bands[1:])]
 
 

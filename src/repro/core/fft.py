@@ -23,6 +23,7 @@ used by the Pallas kernel (kernels/fft); complex dtypes appear only in tests.
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core.shuffle import bit_reverse_indices
@@ -111,6 +112,20 @@ def fft_bitrev(re, im=None, *, inverse: bool = False):
     return re, im
 
 
+def _negate_index(z):
+    """z[..., (m - k) % m] for a power-of-two m, by rotates and selects
+    (Mosaic lowers no gather or lane reversal): flipping every index bit
+    reverses the axis, and one rotate turns m-1-k into m-k."""
+    m = z.shape[-1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, z.shape, z.ndim - 1)
+    b = 1
+    while b < m:                                   # z[p] <- z[p ^ b]
+        z = jnp.where((pos & b) != 0, jnp.roll(z, b, axis=-1),
+                      jnp.roll(z, -b, axis=-1))
+        b *= 2
+    return jnp.roll(z, 1, axis=-1)
+
+
 def untangle_rfft(Zr, Zi, wr, wi):
     """Untangle the packed N/2 spectrum Z into the length-(N/2 + 1) rfft:
     X[k] = (Z[k]+conj(Z[-k]))/2 - i/2 * e^{-2pi i k/N} (Z[k]-conj(Z[-k])),
@@ -119,9 +134,7 @@ def untangle_rfft(Zr, Zi, wr, wi):
     wr/wi: the (m,) cos/sin of -2*pi*k/N. The single source of the epilogue
     math — shared by this module, kernels/fft/ops.py, and the fused
     application kernel (kernels/pipeline)."""
-    m = Zr.shape[-1]
-    idx = (-jnp.arange(m)) % m                     # Z[N/2 - k] with wrap
-    Zcr, Zci = Zr[..., idx], -Zi[..., idx]         # conj(Z[-k])
+    Zcr, Zci = _negate_index(Zr), -_negate_index(Zi)   # conj(Z[-k])
     er, ei = (Zr + Zcr) * 0.5, (Zi + Zci) * 0.5
     or_, oi = (Zr - Zcr) * 0.5, (Zi - Zci) * 0.5
     # prod = w * o; then (-i*prod).re = prod.im, (-i*prod).im = -prod.re

@@ -17,6 +17,7 @@ shift amount is a static parameter (default 32 = the paper's hardcoded value).
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 HALF_LOWER = "lower"
@@ -60,6 +61,25 @@ def bit_reverse_indices(n: int) -> np.ndarray:
     return rev
 
 
+def bit_reverse_lanes(x):
+    """x[..., bit_reverse_indices(n)] by rotates and selects (Mosaic has
+    no lane gather): reversing the index bits is log2(n)/2 swaps of bit
+    pairs (i, j), and each swap moves the words whose two bits differ by
+    +-(2^j - 2^i)."""
+    n = x.shape[-1]
+    bits = int(np.log2(n))
+    assert 1 << bits == n, f"{n} not a power of two"
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    for i in range(bits // 2):
+        j = bits - 1 - i
+        d = (1 << j) - (1 << i)
+        bi, bj = (pos >> i) & 1, (pos >> j) & 1
+        x = jnp.where((bi == 1) & (bj == 0), jnp.roll(x, -d, axis=-1),
+                      jnp.where((bi == 0) & (bj == 1),
+                                jnp.roll(x, d, axis=-1), x))
+    return x
+
+
 def bit_reverse(a, b, half: str = "both"):
     """Bit-reversal permutation of concat(A, B)."""
     x = jnp.concatenate([a, b], axis=-1)
@@ -74,6 +94,26 @@ def circular_shift(a, b, amount: int = 32, half: str = "both"):
     return _take_half(jnp.roll(x, amount, axis=-1), half)
 
 
+def compact_even(x):
+    """y[..., j] = x[..., 2j] for j < L/2; the upper half is don't-care.
+
+    Even-index pruning as log2(L/2) rotate + select steps, because Mosaic
+    has no lane-strided slice. Step b moves each kept word whose
+    destination has bit b set left by 2^b; before step b every kept word
+    sits in an even 2^b-block, so no step overwrites one."""
+    L = x.shape[-1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    b = 0
+    while (1 << b) < L // 2:
+        x = jnp.where(((pos >> b) & 1) == 1,
+                      jnp.roll(x, -(1 << b), axis=-1), x)
+        b += 1
+    return x
+
+
 def deinterleave(x):
-    """Inverse of interleave: (..., 2N) -> even stream, odd stream."""
-    return x[..., 0::2], x[..., 1::2]
+    """Inverse of interleave: (..., 2N) -> even stream, odd stream.
+    Rotates and selects only, so it runs inside Mosaic kernels too."""
+    n = x.shape[-1] // 2
+    return (compact_even(x)[..., :n],
+            compact_even(jnp.roll(x, -1, axis=-1))[..., :n])

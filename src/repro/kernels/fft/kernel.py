@@ -1,11 +1,12 @@
-"""Pallas TPU kernel: batched radix-2 Stockham FFT (paper §3.4 dataflow).
+"""Pallas TPU kernel: batched radix-2 FFT (paper §3.4 dataflow).
 
 One grid step = one (rows x N) batch block staged into VMEM. The whole
-log2(N)-stage pipeline runs on the staged block: butterflies on the VPU,
-the inter-stage *words interleaving* as register reshapes — data makes ONE
-HBM->VMEM round trip for the entire FFT, which is precisely the paper's
-SPM->VWR->datapath staging claim, transplanted. Twiddles are a packed
-(log2 N, N/2) table, computed host-side in f64 and staged once (the paper
+log2(N)-stage pipeline runs on the staged block: in-place butterflies on
+the VPU with their operands brought alongside by lane rotates, then the
+shuffle unit's bit-reversal — data makes ONE HBM->VMEM round trip for the
+entire FFT, which is precisely the paper's SPM->VWR->datapath staging
+claim, transplanted. Twiddles are a (log2 N, N) table in the flat layout,
+computed host-side in f64 and staged once (the paper
 stores them in the SPM; the FFT accelerator it compares against burns ROMs).
 
 Working set: re + im + twiddles = 3 "VWR" blocks (core/vwr.py budget).
@@ -22,51 +23,70 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.core.shuffle import bit_reverse_lanes
 from repro.core.vwr import VWRSpec, resolve_block_rows
 
 
 def twiddle_table(n: int, inverse: bool = False) -> tuple[np.ndarray, np.ndarray]:
-    """(stages, n//2) packed twiddles; stage s covers group length n >> s."""
+    """(stages, n) twiddles in the flat layout of
+    `radix2_stages`: at stage s (group length L = n >> s) lane p in the
+    upper half of its group holds w_L^((p mod L) - L/2); lower-half lanes
+    hold 0 and are never read."""
     stages = int(np.log2(n))
-    wr = np.zeros((stages, n // 2), np.float32)
-    wi = np.zeros((stages, n // 2), np.float32)
+    wr = np.zeros((stages, n), np.float32)
+    wi = np.zeros((stages, n), np.float32)
+    p = np.arange(n)
     for s in range(stages):
         m = n >> s               # current group length
-        j = np.arange(m // 2)
+        upper = (p % m) >= m // 2
+        j = (p % m) - m // 2
         ang = -2.0 * np.pi * j / m
         if inverse:
             ang = -ang
-        # tile so every group in the stage reads lane-aligned twiddles
-        wr[s] = np.tile(np.cos(ang), n // m).astype(np.float32)
-        wi[s] = np.tile(np.sin(ang), n // m).astype(np.float32)
+        wr[s] = np.where(upper, np.cos(ang), 0.0).astype(np.float32)
+        wi[s] = np.where(upper, np.sin(ang), 0.0).astype(np.float32)
     return wr, wi
 
 
-def fft_kernel(re_ref, im_ref, wr_ref, wi_ref, ore_ref, oim_ref, *,
-               stages: int):
-    re = re_ref[...].astype(jnp.float32)    # (rb, N)
-    im = im_ref[...].astype(jnp.float32)
-    rb, n_total = re.shape
-    g, n = 1, n_total
-    re = re.reshape(rb, 1, n_total)
-    im = im.reshape(rb, 1, n_total)
+def radix2_stages(re, im, wr_ref, wi_ref, stages: int):
+    """The log2(N) radix-2 DIF stages on (rb, N) planes in the paper's
+    literal in-place dataflow (§3.4): stage s splits each group of
+    L = N >> s lanes into halves a, b; lower lanes get a + b, upper lanes
+    (a - b) * w — two lane rotates per plane, the butterfly and a select,
+    on lane-dense vectors only (the grouped (rb, groups, half) layout
+    pads every tiny trailing dim to a full tile and overflows VMEM). The
+    result leaves the stages in bit-reversed order, and the final
+    `bit_reverse_lanes` shuffle restores natural order. Every butterfly
+    sees the same operands and twiddle as in the self-sorting form, so the
+    output is bit-identical to it."""
+    n_total = re.shape[-1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, re.shape, re.ndim - 1)
+    h = n_total // 2
     for s in range(stages):
-        ar, ai = re[..., : n // 2], im[..., : n // 2]
-        br, bi = re[..., n // 2:], im[..., n // 2:]
-        wr = wr_ref[s, : n // 2].reshape(1, 1, n // 2)
-        wi = wi_ref[s, : n // 2].reshape(1, 1, n // 2)
+        upper = (pos & h) != 0
+        wr = wr_ref[s: s + 1, :]
+        wi = wi_ref[s: s + 1, :]
+        ar = jnp.where(upper, jnp.roll(re, h, axis=-1), re)
+        ai = jnp.where(upper, jnp.roll(im, h, axis=-1), im)
+        br = jnp.where(upper, re, jnp.roll(re, -h, axis=-1))
+        bi = jnp.where(upper, im, jnp.roll(im, -h, axis=-1))
         t0r, t0i = ar + br, ai + bi
         dr, di = ar - br, ai - bi
         t1r = dr * wr - di * wi
         t1i = dr * wi + di * wr
-        # words-interleaving regroup (self-sorting Stockham)
-        re = jnp.concatenate([t0r[:, None], t1r[:, None]], axis=1).reshape(
-            rb, 2 * g, n // 2)
-        im = jnp.concatenate([t0i[:, None], t1i[:, None]], axis=1).reshape(
-            rb, 2 * g, n // 2)
-        g, n = 2 * g, n // 2
-    ore_ref[...] = re.reshape(rb, n_total).astype(ore_ref.dtype)
-    oim_ref[...] = im.reshape(rb, n_total).astype(oim_ref.dtype)
+        re = jnp.where(upper, t1r, t0r)
+        im = jnp.where(upper, t1i, t0i)
+        h //= 2
+    return bit_reverse_lanes(re), bit_reverse_lanes(im)
+
+
+def fft_kernel(re_ref, im_ref, wr_ref, wi_ref, ore_ref, oim_ref, *,
+               stages: int):
+    re, im = radix2_stages(re_ref[...].astype(jnp.float32),
+                             im_ref[...].astype(jnp.float32),
+                             wr_ref, wi_ref, stages)
+    ore_ref[...] = re.astype(ore_ref.dtype)
+    oim_ref[...] = im.astype(oim_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -90,9 +110,9 @@ def fft_pallas(re, im, *, inverse: bool = False, interpret: bool = True,
         in_specs=[
             pl.BlockSpec((rb, N), lambda i: (i, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((rb, N), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((stages, N // 2), lambda i: (0, 0),
+            pl.BlockSpec((stages, N), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((stages, N // 2), lambda i: (0, 0),
+            pl.BlockSpec((stages, N), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(

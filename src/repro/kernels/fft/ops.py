@@ -5,11 +5,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.fft.kernel import fft_pallas
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def fft(re, im=None, *, inverse: bool = False,
@@ -20,7 +18,7 @@ def fft(re, im=None, *, inverse: bool = False,
     per shape) instead of the static VWRSpec budget."""
     if im is None:
         im = jnp.zeros_like(re)
-    interp = _interpret()
+    interp = interpret_mode()
     if autotune and block_rows is None:
         from repro.core.autotune import tuned_block_rows
 

@@ -1,13 +1,9 @@
 """Public jit'd API for the FIR kernel."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.fir.kernel import fir_pallas
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def fir(x, taps, *, seq_block: int = 2048,
@@ -19,7 +15,7 @@ def fir(x, taps, *, seq_block: int = 2048,
     squeeze = x.ndim == 1
     if squeeze:
         x = x[None, :]
-    interp = _interpret()
+    interp = interpret_mode()
     if autotune and block_rows is None:
         from repro.core.autotune import tuned_block_rows
 

@@ -1,13 +1,9 @@
 """Public jit'd API for the flash-attention kernel."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
@@ -16,4 +12,4 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
     q: (B,Sq,H,dh); k,v: (B,Skv,KV,dh)."""
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   q_chunk=q_chunk, kv_chunk=kv_chunk,
-                                  interpret=_interpret())
+                                  interpret=interpret_mode())

@@ -45,10 +45,12 @@ import functools
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.biosignal import log1p
 from repro.kernels.pipeline.graph import (OutputSpec, build_graph,
                                           register_graph_factory,
                                           stream_frame_count)
-from repro.kernels.pipeline.kernel import _packed_rfft, _table_operands
+from repro.kernels.pipeline.kernel import (HIGHEST, _packed_rfft,
+                                           _table_operands)
 from repro.kernels.pipeline.stages import register_stage
 
 __all__ = ["AsrFrontendApp", "make_asr_frontend", "mel_filterbank",
@@ -126,7 +128,7 @@ def _hann_body(state, tables, params):
                 requires=("windowed",), produces=("power",))
 def _power_body(state, tables, params):
     """|rFFT|^2 of the windowed segment via the shared packed-rFFT helper
-    (`kernel.py:_packed_rfft`) — same Stockham stages and staged twiddle/
+    (`kernel.py:_packed_rfft`) — same radix-2 stages and staged twiddle/
     untangle tables as the biosignal graph, WITHOUT its mean subtraction
     (spectral features keep the DC bin)."""
     Xr, Xi = _packed_rfft(state["windowed"], tables["twiddle_re"],
@@ -141,8 +143,8 @@ def _logmel_body(state, tables, params):
     """log1p(power @ mel_w): the mel matmul epilogue on the MXU. ``log1p``
     not ``log`` so silent frames (power -> 0) stay finite and the host
     comparison is well-posed at f32."""
-    return {"logmel": jnp.log1p(jnp.dot(
-        state["power"], tables["mel_w"][...],
+    return {"logmel": log1p(jnp.dot(
+        state["power"], tables["mel_w"][...], precision=HIGHEST,
         preferred_element_type=jnp.float32))}
 
 
@@ -225,7 +227,7 @@ def asr_reference_frames(app: AsrFrontendApp, frames) -> dict:
     """Librosa-style host oracle on pre-framed (n, window) windows:
     frame-local pre-emphasis (zero history per frame, the `core.fir`
     convention), periodic Hann, ``np.fft.rfft`` (float64 twiddles —
-    numerics independent of the kernel's packed Stockham path), slaney
+    numerics independent of the kernel's packed radix-2 path), slaney
     mel matmul, log1p. The fused graph matches this to scale-relative
     f32 tolerance — the `tests/test_asr.py` pin."""
     x = np.asarray(frames, np.float32)

@@ -25,10 +25,15 @@ Invariants (pinned by `tests/test_stage_graph.py` / `tests/test_asr.py`):
   every (window, hop, outputs, ring_depth).
 * **FIR-first / hop-alignment.** Every graph's first stage is a causal
   k-tap FIR (`stages.Stage` kind ``"fir"``). The stream/ring framing —
-  body chunk + hop-sized tail specs, FIR once over the chunk, the
-  frame-local zero-history head patch of the first ``n_taps - 1``
-  columns — is keyed off that stage's tap count and is what makes raw
-  hop-aligned chunk feeds bit-identical to host framing for ANY graph.
+  the signal as rows of ``hop`` samples, a body row block plus the next
+  block as its tail, FIR once over the laid-out chunk, the frame-local
+  zero-history head patch of the first ``n_taps - 1`` columns — is keyed
+  off that stage's tap count and is what makes raw hop-aligned chunk
+  feeds bit-identical to host framing for ANY graph.
+* **Mosaic-legal.** Every body compiles for the TPU v5e
+  (`tests/test_tpu_compile.py`): row blocks are (8, 128)-tiled, slices
+  start on whole rows, and the stages use rotates and selects where a
+  gather, strided slice or scatter would not lower.
 * **Generic elision.** A registered stage runs only when a *requested*
   output transitively depends on it (`stages_to_run`); unrequested
   outputs are never written to HBM (their out specs don't exist). This
@@ -51,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.vwr import VWRSpec, resolve_block_rows
+from repro.core.vwr import SUBLANES, VWRSpec, resolve_block_rows, round_up
 from repro.kernels.pipeline.stages import (OperandMismatchError,
                                            StageGraphError,
                                            UnknownGraphError, get_stage,
@@ -87,8 +92,10 @@ def resolve_stream_block_frames(n_frames: int, window: int, hop: int,
     need not divide (or even stay below) the frame count — the signal is
     zero-padded and the garbage tail frames are trimmed after the call.
     Never below `min_stream_block_frames`: the tail chunk holds only
-    block_frames*hop samples, which must cover the window-hop spill."""
-    rb = override or min(max(n_frames, 1), 8)
+    block_frames*hop samples, which must cover the window-hop spill. The
+    default is one sublane tile of frames, however few the frames: the
+    kernel's row blocks must tile (8, 128) on the TPU."""
+    rb = override or SUBLANES
     return max(1, rb, min_stream_block_frames(window, hop))
 
 
@@ -379,46 +386,58 @@ def _run_graph(graph: StageGraph, filt, tables: dict, outputs: tuple):
     return state
 
 
-def graph_kernel(*refs, graph: StageGraph, outputs: tuple):
-    """Pre-framed graph body: one (rb, S) block staged once, the FIR-first
-    stage chain, one HBM write (the generic `kernel.py:pipeline_kernel`)."""
-    n_ops = len(graph.operands)
-    x_ref = refs[0]
-    tables = dict(zip(graph.operands, refs[1: 1 + n_ops]))
-    out_refs = dict(zip(outputs, refs[1 + n_ops:]))
-    x = x_ref[...].astype(jnp.float32)             # (rb, S) staged once
+def _graph_body(graph: StageGraph, x, tables: dict, out_refs: dict,
+                outputs: tuple) -> None:
+    """One staged (rb, window) frame block -> FIR-first stage chain -> one
+    HBM write. Shared by every grid: framed, stream and ring."""
     filt = _fir_stage(x, tables[graph.stages[0].operands[0]], graph.n_taps)
     _write_graph_outputs(graph, out_refs,
                          _run_graph(graph, filt, tables, outputs))
 
 
+def graph_kernel(*refs, graph: StageGraph, outputs: tuple):
+    """Pre-framed graph body: one (rb, S) block staged once, the FIR-first
+    stage chain, one HBM write (the generic `kernel.py:pipeline_kernel`)."""
+    n_ops = len(graph.operands)
+    tables = dict(zip(graph.operands, refs[1: 1 + n_ops]))
+    out_refs = dict(zip(outputs, refs[1 + n_ops:]))
+    _graph_body(graph, refs[0][...].astype(jnp.float32), tables, out_refs,
+                outputs)
+
+
 def graph_stream_kernel(*refs, graph: StageGraph, window: int, hop: int,
                         block_frames: int, outputs: tuple, n_tails: int):
-    """Raw-signal graph body with IN-KERNEL framing — the generic
-    `kernel.py:pipeline_stream_kernel`: one body chunk + `n_tails`
-    hop-sized tail views of the same signal, the graph's FIR once over
-    the chunk, frames cut by static hop slices, and the first
-    ``n_taps - 1`` columns patched with frame-local zero history so the
-    result is bit-identical to running the graph on host-framed windows.
-    Shared verbatim by the (slot, block) ring grid."""
-    n_taps = graph.n_taps
-    body_ref, tail_refs = refs[0], refs[1: 1 + n_tails]
+    """Raw-signal graph body with IN-KERNEL framing. The signal arrives as
+    rows of ``hop`` samples: the body block holds this step's
+    `block_frames` rows, and (when window > hop) the tail block is the
+    next step's rows, which supply the window - hop overlap spill. The
+    rows are laid end to end into one (1, span) chunk (whole rows only,
+    so no slice starts off the (8, 128) tile), the graph's FIR runs once
+    over the chunk, frames are cut by static hop slices, and the first
+    ``n_taps - 1`` columns are patched with frame-local zero history, so
+    the result is bit-identical to running the graph on host-framed
+    windows. Shared verbatim by the (slot, block) ring grid."""
+    n_taps, rb = graph.n_taps, block_frames
+    rows = refs[0][...]
+    if n_tails:
+        rows = jnp.concatenate([rows, refs[1][...]], axis=0)
     i = 1 + n_tails
     tables = dict(zip(graph.operands, refs[i: i + len(graph.operands)]))
     out_refs = dict(zip(outputs, refs[i + len(graph.operands):]))
     taps_ref = tables[graph.stages[0].operands[0]]
+    span = (rb - 1) * hop + window
     chunk = jnp.concatenate(
-        [r[0, :] for r in (body_ref,) + tuple(tail_refs)]
-    )[: block_frames * hop + (window - hop)].astype(jnp.float32)
+        [rows[r: r + 1] for r in range(-(-span // hop))], axis=1
+    )[:, :span].astype(jnp.float32)
     # FIR once over the chunk (overlap shared in VMEM)
-    filt_chunk = _fir_stage(chunk[None, :], taps_ref, n_taps)[0]
-    filt = jnp.stack([filt_chunk[r * hop: r * hop + window]
-                      for r in range(block_frames)])
+    filt_chunk = _fir_stage(chunk, taps_ref, n_taps)
+    filt = jnp.concatenate([filt_chunk[:, r * hop: r * hop + window]
+                            for r in range(rb)], axis=0)
     # frame-local FIR transient: the framed reference zero-pads each
     # frame's history, the chunk FIR used real preceding samples — patch
     # the first n_taps-1 columns (the only ones that can differ)
-    head = jnp.stack([chunk[r * hop: r * hop + n_taps - 1]
-                      for r in range(block_frames)])
+    head = jnp.concatenate([chunk[:, r * hop: r * hop + n_taps - 1]
+                            for r in range(rb)], axis=0)
     filt = jnp.concatenate([_fir_stage(head, taps_ref, n_taps),
                             filt[:, n_taps - 1:]], axis=1)
     _write_graph_outputs(graph, out_refs,
@@ -489,13 +508,40 @@ def graph_frames_call(frames, operands, *, graph: StageGraph,
     return _graph_as_output_dict(graph, outs, outputs, R)
 
 
+def _stream_layout(n: int, window: int, hop: int,
+                   block_frames: int | None):
+    """Grid arithmetic shared by the stream and ring entries: frames per
+    step ``rb`` (a sublane multiple, so (rb, hop) row blocks tile), the
+    step count, whether a tail block is needed (window > hop), and the
+    hop-sized rows the padded signal must hold — one block more than the
+    steps when a tail is read, since the last step's tail is the block
+    after it."""
+    rb = round_up(resolve_stream_block_frames(n, window, hop, block_frames),
+                  SUBLANES)
+    n_blocks = -(-n // rb)
+    n_tails = int(window > hop)
+    return rb, n_blocks, n_tails, (n_blocks + n_tails) * rb
+
+
+def _row_specs(rb: int, hop: int, n_tails: int, ring: bool) -> list:
+    """Body (+ tail) BlockSpecs over the (rows, hop) signal, or the
+    (slot, rows, hop) ring: the tail is the SAME array one block ahead."""
+    if ring:
+        return [pl.BlockSpec((None, rb, hop), lambda r, j, t=t: (r, j + t, 0),
+                             memory_space=pltpu.VMEM)
+                for t in range(1 + n_tails)]
+    return [pl.BlockSpec((rb, hop), lambda j, t=t: (j + t, 0),
+                         memory_space=pltpu.VMEM)
+            for t in range(1 + n_tails)]
+
+
 def graph_stream_call(signal, operands, *, graph: StageGraph, window: int,
                       hop: int, interpret: bool = True,
                       block_frames: int | None = None, outputs=None):
     """Unjitted raw-signal streaming core (jit wrapper:
-    `graph_stream_pallas`). Exactly ONE `pallas_call` per call; the
-    framing/padding arithmetic is the legacy
-    `kernel.py:pipeline_stream_pallas` unchanged."""
+    `graph_stream_pallas`). Exactly ONE `pallas_call` per call; the signal
+    is zero-padded (or cut) to whole blocks of hop-sized rows and the
+    garbage tail frames are trimmed after the call."""
     outputs = canonical_graph_outputs(graph, outputs)
     (S,) = signal.shape
     assert window >= graph.fft_size, (window, graph.fft_size)
@@ -503,24 +549,12 @@ def graph_stream_call(signal, operands, *, graph: StageGraph, window: int,
     n = stream_frame_count(S, window, hop)
     if n == 0:
         return graph_empty_outputs(graph, window, signal.dtype, outputs)
-    rb = resolve_stream_block_frames(n, window, hop, block_frames)
-    n_blocks = -(-n // rb)
-    L = rb * hop                     # body chunk: one block's sample stride
-    n_tails = min_stream_block_frames(window, hop) if window > hop else 0
-    # hop-granular padding: every spec must tile the padded signal, so pad
-    # the hop count up to a multiple of rb (zeros; garbage frames trimmed)
-    total = -(-(n_blocks * rb + n_tails) // rb) * L
+    rb, n_blocks, n_tails, rows = _stream_layout(n, window, hop, block_frames)
+    total = rows * hop
     sig = signal[:min(S, total)]
     if total > sig.shape[0]:
         sig = jnp.concatenate(
             [sig, jnp.zeros((total - sig.shape[0],), sig.dtype)])
-    sig2 = sig.reshape(1, total)
-    in_specs = [pl.BlockSpec((1, L), lambda j: (0, j),
-                             memory_space=pltpu.VMEM)]
-    for i in range(n_tails):         # the SAME signal, i hop-blocks ahead
-        in_specs.append(pl.BlockSpec(
-            (1, hop), lambda j, i=i: (0, j * rb + rb + i),
-            memory_space=pltpu.VMEM))
     out_shape, out_specs = _graph_out_shapes_specs(
         graph, n_blocks * rb, rb, window, signal.dtype, outputs)
     outs = pl.pallas_call(
@@ -528,11 +562,12 @@ def graph_stream_call(signal, operands, *, graph: StageGraph, window: int,
                           hop=hop, block_frames=rb, outputs=outputs,
                           n_tails=n_tails),
         out_shape=out_shape,
-        in_specs=in_specs + _operand_specs(operands),
+        in_specs=_row_specs(rb, hop, n_tails, ring=False)
+        + _operand_specs(operands),
         out_specs=out_specs,
         grid=(n_blocks,),
         interpret=interpret,
-    )(*((sig2,) * (1 + n_tails)), *operands)
+    )(*((sig.reshape(rows, hop),) * (1 + n_tails)), *operands)
     return _graph_as_output_dict(graph, outs, outputs, n)
 
 
@@ -541,7 +576,7 @@ def graph_ring_call(ring, operands, *, graph: StageGraph, window: int,
                     block_frames: int | None = None, outputs=None):
     """Unjitted ring core (jit wrapper: `graph_ring_pallas`): a
     (ring_depth, span) ring of raw chunks through ONE `pallas_call` on a
-    (slot, block) grid, the stream body/tail index_maps reused verbatim
+    (slot, block) grid, the stream body/tail row blocks reused verbatim
     per slot. Slot r of the result is bit-identical to
     `graph_stream_call(ring[r], ...)` — the device-resident loop's
     dispatch contract."""
@@ -551,24 +586,15 @@ def graph_ring_call(ring, operands, *, graph: StageGraph, window: int,
     assert 0 < hop <= window, (hop, window)
     n = stream_frame_count(span, window, hop)      # frames per ring slot
     assert n > 0, f"ring span {span} shorter than one {window}-window"
-    rb = resolve_stream_block_frames(n, window, hop, block_frames)
-    n_blocks = -(-n // rb)
-    L = rb * hop                     # body chunk: one block's sample stride
-    n_tails = min_stream_block_frames(window, hop) if window > hop else 0
-    # pad every slot row to the block tiling (same hop-granular arithmetic
-    # as the single-chunk entry; the pad frames are trimmed per slot)
-    total = -(-(n_blocks * rb + n_tails) // rb) * L
+    rb, n_blocks, n_tails, rows = _stream_layout(n, window, hop, block_frames)
+    total = rows * hop
+    # pad every slot row to the block tiling (the pad frames are trimmed
+    # per slot)
     if total > span:
         ring = jnp.concatenate(
             [ring, jnp.zeros((D, total - span), ring.dtype)], axis=1)
     else:
         ring = ring[:, :total]
-    in_specs = [pl.BlockSpec((1, L), lambda r, j: (r, j),
-                             memory_space=pltpu.VMEM)]
-    for i in range(n_tails):         # the SAME slot row, i hop-blocks ahead
-        in_specs.append(pl.BlockSpec(
-            (1, hop), lambda r, j, i=i: (r, j * rb + rb + i),
-            memory_space=pltpu.VMEM))
     out_shape, out_specs = _graph_out_shapes_specs(
         graph, D * n_blocks * rb, rb, window, ring.dtype, outputs,
         index_map=lambda r, j: (r * n_blocks + j, 0))
@@ -577,11 +603,12 @@ def graph_ring_call(ring, operands, *, graph: StageGraph, window: int,
                           hop=hop, block_frames=rb, outputs=outputs,
                           n_tails=n_tails),
         out_shape=out_shape,
-        in_specs=in_specs + _operand_specs(operands),
+        in_specs=_row_specs(rb, hop, n_tails, ring=True)
+        + _operand_specs(operands),
         out_specs=out_specs,
         grid=(D, n_blocks),
         interpret=interpret,
-    )(*((ring,) * (1 + n_tails)), *operands)
+    )(*((ring.reshape(D, rows, hop),) * (1 + n_tails)), *operands)
     res = _graph_as_output_dict(graph, outs, outputs, D * n_blocks * rb)
     # per-slot trim: every slot framed n_blocks*rb rows, keep its n real
     # frames and restore the (ring_depth, n, ...) slot structure

@@ -17,7 +17,7 @@ kernel:
                                predicated RC code), on the VMEM-resident
                                filtered block,
       3. time features       — masked interval statistics,
-      4. 512-pt packed rFFT  — the Stockham stages of the FFT kernel with a
+      4. 512-pt packed rFFT  — the radix-2 stages of the FFT kernel with a
                                staged twiddle table + untangle epilogue,
                                reduced to 6 log-band powers,
       5. linear SVM          — margin + argmax class,
@@ -27,9 +27,9 @@ Inter-stage tensors never leave the block: the working set is budgeted
 against `VWRSpec(n_vwrs=4)` (raw + filtered + FFT planes + table/epilogue
 scratch). Numerics follow `core.biosignal` op-for-op so the fused outputs
 match the staged app to f32 tolerance. The delineation/median stage runs a
-fixed-size odd-even sorting network off staged mask tables (no `sort` /
-`take_along_axis` / gather anywhere in the kernel — the former
-Mosaic-compile gap is closed).
+fixed-size odd-even sorting network with iota-arithmetic masks (no `sort`
+/ `take_along_axis` / gather anywhere in the kernel), and every stage
+compiles for the TPU v5e (`tests/test_tpu_compile.py`).
 
 `pipeline_stream_pallas` is the RAW-SIGNAL entry: the grid iterates
 frame-blocks over a 1-D signal and the overlapping (window, hop) frames
@@ -61,11 +61,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.biosignal import (INTERVAL_SLOTS, band_power_features,
-                                  delineate, interval_time_features,
-                                  make_app, oddeven_tables)
+from repro.core.biosignal import (band_power_features, delineate,
+                                  interval_time_features, make_app)
 from repro.core.fft import untangle_rfft
-from repro.kernels.fft.kernel import twiddle_table
+from repro.core.shuffle import deinterleave
+from repro.kernels.fft.kernel import radix2_stages, twiddle_table
 from repro.kernels.pipeline.graph import (OutputSpec, _fir_stage,
                                           build_graph, graph_frames_call,
                                           graph_ring_call,
@@ -79,6 +79,10 @@ from repro.kernels.pipeline.graph import ring_chunk_samples  # noqa: F401
 from repro.kernels.pipeline.graph import stream_frame_count  # noqa: F401
 from repro.kernels.pipeline.stages import register_stage
 
+# f32 matmuls in the kernel bodies: Mosaic's default contraction may drop
+# to bf16 passes, the interpreter's never does
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def untangle_table(fft_size: int) -> np.ndarray:
     """(2, m) packed untangle factors e^{-2*pi*i*k/N} for the real-FFT
@@ -91,37 +95,16 @@ def untangle_table(fft_size: int) -> np.ndarray:
 
 def _packed_rfft(seg, wr_ref, wi_ref, u_ref, *, fft_size: int):
     """Packed real FFT of a VMEM-resident (rb, fft_size) block: N real ->
-    N/2+1 complex via Stockham stages on the packed half-length signal +
-    the untangle epilogue. The butterfly stages are the FFT kernel's body
-    verbatim, reading the staged (stages, m/2) twiddle table and the
+    N/2+1 complex via radix-2 stages on the packed half-length signal +
+    the untangle epilogue. The butterfly stages are the FFT kernel's
+    (`radix2_stages`), reading the staged (stages, m) twiddle table and the
     (2, m) untangle table. Returns ``(Xr, Xi)``, each (rb, fft/2+1).
     Shared by the biosignal band-power stage (mean-subtracted input) and
     the ASR power-spectrum stage (raw windowed input) — the in-kernel
     mirror of `core.fft.rfft_packed`."""
-    rb = seg.shape[0]
-    zr, zi = seg[:, 0::2], seg[:, 1::2]            # pack: z = even + i*odd
-    m = fft_size // 2
-    stages = int(np.log2(m))
-    g, n = 1, m
-    re = zr.reshape(rb, 1, m)
-    im = zi.reshape(rb, 1, m)
-    for s in range(stages):
-        ar, ai = re[..., : n // 2], im[..., : n // 2]
-        br, bi = re[..., n // 2:], im[..., n // 2:]
-        wr = wr_ref[s, : n // 2].reshape(1, 1, n // 2)
-        wi = wi_ref[s, : n // 2].reshape(1, 1, n // 2)
-        t0r, t0i = ar + br, ai + bi
-        dr, di = ar - br, ai - bi
-        t1r = dr * wr - di * wi
-        t1i = dr * wi + di * wr
-        # words-interleaving regroup (self-sorting Stockham)
-        re = jnp.concatenate([t0r[:, None], t1r[:, None]], axis=1).reshape(
-            rb, 2 * g, n // 2)
-        im = jnp.concatenate([t0i[:, None], t1i[:, None]], axis=1).reshape(
-            rb, 2 * g, n // 2)
-        g, n = 2 * g, n // 2
-    Zr = re.reshape(rb, m)
-    Zi = im.reshape(rb, m)
+    zr, zi = deinterleave(seg)                     # pack: z = even + i*odd
+    Zr, Zi = radix2_stages(zr, zi, wr_ref, wi_ref,
+                             int(np.log2(fft_size // 2)))
     return untangle_rfft(Zr, Zi, u_ref[0, :], u_ref[1, :])
 
 
@@ -151,25 +134,22 @@ def canonical_outputs(outputs) -> tuple:
     return tuple(o for o in OUTPUTS if o in sel)
 
 
-def _stages_from_filtered(filt, wr_ref, wi_ref, u_ref, w_ref, b_ref,
-                          sort_tables, *, fft_size: int):
+def _stages_from_filtered(filt, wr_ref, wi_ref, u_ref, w_ref, b_ref, *,
+                          fft_size: int):
     """Stages 2-4 on a VMEM-resident filtered block: delineation mask
     algebra -> masked interval time features + packed-rFFT band powers ->
-    linear SVM margin/class. Shared by the framed and raw-stream kernels.
-    ``sort_tables`` are the staged odd-even network masks for the interval
-    median (kept in VMEM beside the twiddles, like the paper's SPM
-    tables)."""
+    linear SVM margin/class. Shared by the framed and raw-stream kernels."""
     # --- stage 2: delineation (predicated mask algebra, never leaves VMEM)
     is_max, is_min = delineate(filt)
     # --- stage 3a: time features (masked interval statistics) ---
-    f_time = interval_time_features(is_max, is_min, sort_tables=sort_tables)
+    f_time = interval_time_features(is_max, is_min)
     # --- stage 3b: frequency features (packed rFFT band powers) ---
     f_freq = _rfft_band_powers(filt[:, :fft_size], wr_ref, wi_ref, u_ref,
                                fft_size=fft_size)
     feats = jnp.stack(f_time + f_freq, axis=-1)    # (rb, 12)
     # --- stage 4: linear SVM margin + class ---
-    margin = jnp.dot(feats, w_ref[...], preferred_element_type=jnp.float32
-                     ) + b_ref[0]
+    margin = jnp.dot(feats, w_ref[...], precision=HIGHEST,
+                     preferred_element_type=jnp.float32) + b_ref[0]
     cls = jnp.argmax(margin, axis=-1).astype(jnp.int32)
     return feats, margin, cls
 
@@ -187,7 +167,7 @@ def _write_outputs(refs: dict, filt, feats, margin, cls):
 
 
 def pipeline_kernel(x_ref, taps_ref, wr_ref, wi_ref, u_ref, w_ref, b_ref,
-                    lo_ref, hi_ref, ks_ref, *out_refs, n_taps: int,
+                    *out_refs, n_taps: int,
                     fft_size: int, outputs: tuple = OUTPUTS):
     refs = dict(zip(outputs, out_refs))
     x = x_ref[...].astype(jnp.float32)             # (rb, S) staged once
@@ -196,31 +176,27 @@ def pipeline_kernel(x_ref, taps_ref, wr_ref, wi_ref, u_ref, w_ref, b_ref,
     feats = margin = cls = None
     if outputs != ("filtered",):
         feats, margin, cls = _stages_from_filtered(
-            filt, wr_ref, wi_ref, u_ref, w_ref, b_ref,
-            (lo_ref[...], hi_ref[...], ks_ref[...]), fft_size=fft_size)
+            filt, wr_ref, wi_ref, u_ref, w_ref, b_ref, fft_size=fft_size)
     _write_outputs(refs, filt, feats, margin, cls)
 
 
 def _table_operands(taps, w, b, fft_size: int):
     """The staged constant tables every pipeline kernel reads: FIR taps,
-    Stockham twiddles, untangle factors, SVM weights/bias, and the
-    fixed-size (INTERVAL_SLOTS) odd-even sorting-network stage masks for
-    the interval median — with their (broadcast) VMEM BlockSpecs."""
+    FFT twiddles, untangle factors and SVM weights/bias — with their
+    (broadcast) VMEM BlockSpecs."""
     k = int(taps.shape[0])
     F, C = w.shape
     m = fft_size // 2
     stages = int(np.log2(m))
     assert 1 << stages == m, f"fft_size={fft_size} not a power of 2"
     wr, wi = twiddle_table(m)
-    lo, hi, ks = oddeven_tables(INTERVAL_SLOTS)
     operands = (jnp.asarray(taps, jnp.float32).reshape(1, k),
                 jnp.asarray(wr), jnp.asarray(wi),
                 jnp.asarray(untangle_table(fft_size)),
                 jnp.asarray(w, jnp.float32),
-                jnp.asarray(b, jnp.float32).reshape(1, C),
-                jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(ks))
-    shapes = ((1, k), (stages, m // 2), (stages, m // 2), (2, m), (F, C),
-              (1, C), lo.shape, hi.shape, ks.shape)
+                jnp.asarray(b, jnp.float32).reshape(1, C))
+    shapes = ((1, k), (stages, m), (stages, m), (2, m), (F, C),
+              (1, C))
     # broadcast index_map takes *any* grid rank: the same tables serve the
     # 1-D framed/stream grids and the 2-D ring grid
     specs = [pl.BlockSpec(s, lambda *_: (0, 0), memory_space=pltpu.VMEM)
@@ -268,17 +244,13 @@ def _delineate_body(state, tables, params):
 
 
 @register_stage("biosignal_features",
-                operands=("twiddle_re", "twiddle_im", "untangle",
-                          "sort_lo", "sort_hi", "sort_ks"),
+                operands=("twiddle_re", "twiddle_im", "untangle"),
                 requires=("filtered", "is_max", "is_min"),
                 produces=("features",))
 def _features_body(state, tables, params):
-    """Masked interval time features (odd-even network median off the
-    staged sort masks) + packed-rFFT band powers, stacked to (rb, 12)."""
-    f_time = interval_time_features(
-        state["is_max"], state["is_min"],
-        sort_tables=(tables["sort_lo"][...], tables["sort_hi"][...],
-                     tables["sort_ks"][...]))
+    """Masked interval time features (odd-even network median) +
+    packed-rFFT band powers, stacked to (rb, 12)."""
+    f_time = interval_time_features(state["is_max"], state["is_min"])
     f_freq = _rfft_band_powers(
         state["filtered"][:, :params["fft_size"]], tables["twiddle_re"],
         tables["twiddle_im"], tables["untangle"],
@@ -291,7 +263,7 @@ def _features_body(state, tables, params):
 def _svm_body(state, tables, params):
     """Linear SVM margin + argmax class — the matmul epilogue stage."""
     margin = jnp.dot(state["features"], tables["svm_w"][...],
-                     preferred_element_type=jnp.float32
+                     precision=HIGHEST, preferred_element_type=jnp.float32
                      ) + tables["svm_b"][0]
     return {"margin": margin,
             "class": jnp.argmax(margin, axis=-1).astype(jnp.int32)}
@@ -312,7 +284,7 @@ def biosignal_graph(n_taps: int, n_features: int, n_classes: int,
          ("class", OutputSpec((), "int32"))),
         # binding order == the `_table_operands` tuple order
         ("fir_taps", "twiddle_re", "twiddle_im", "untangle",
-         "svm_w", "svm_b", "sort_lo", "sort_hi", "sort_ks"),
+         "svm_w", "svm_b"),
         (("n_taps", int(n_taps)), ("fft_size", int(fft_size)),
          ("n_features", int(n_features)), ("n_classes", int(n_classes))))
 
@@ -402,9 +374,8 @@ def pipeline_stream_kernel(*refs, n_taps: int, fft_size: int, window: int,
     """
     body_ref, tail_refs = refs[0], refs[1: 1 + n_tails]
     i = 1 + n_tails
-    (taps_ref, wr_ref, wi_ref, u_ref, w_ref, b_ref, lo_ref, hi_ref,
-     ks_ref) = refs[i: i + 9]
-    refs_out = dict(zip(outputs, refs[i + 9:]))
+    taps_ref, wr_ref, wi_ref, u_ref, w_ref, b_ref = refs[i: i + 6]
+    refs_out = dict(zip(outputs, refs[i + 6:]))
     chunk = jnp.concatenate(
         [r[0, :] for r in (body_ref,) + tuple(tail_refs)]
     )[: block_frames * hop + (window - hop)].astype(jnp.float32)
@@ -422,8 +393,7 @@ def pipeline_stream_kernel(*refs, n_taps: int, fft_size: int, window: int,
     feats = margin = cls = None
     if outputs != ("filtered",):
         feats, margin, cls = _stages_from_filtered(
-            filt, wr_ref, wi_ref, u_ref, w_ref, b_ref,
-            (lo_ref[...], hi_ref[...], ks_ref[...]), fft_size=fft_size)
+            filt, wr_ref, wi_ref, u_ref, w_ref, b_ref, fft_size=fft_size)
     _write_outputs(refs_out, filt, feats, margin, cls)
 
 
@@ -471,10 +441,10 @@ def pipeline_ring_pallas(ring, taps, w, b, *, window: int, hop: int,
     `batch_windows`-frame slot). The grid is `(ring_depth, n_blocks)`:
     the first axis advances the ring slot, the second reuses the
     in-kernel framing index_maps of the single-chunk stream kernel
-    VERBATIM — body BlockSpec `(r, j) -> (r, j)` is block j of slot r's
-    hop arithmetic, the `window-hop` tail specs read the same row
-    `j*rb + rb + i` hop-blocks ahead, and `pipeline_stream_kernel` is the
-    kernel body unchanged. This is the kernel half of the device-resident
+    VERBATIM — slot r is laid out as rows of ``hop`` samples, the body
+    BlockSpec `(r, j) -> (r, j, 0)` is row block j of that slot and the
+    tail BlockSpec the block after it, and `graph.py:graph_stream_kernel`
+    is the kernel body unchanged. This is the kernel half of the device-resident
     streaming loop (`serve/resident.py`): a whole ring of batches
     advances frame-blocks inside one compiled dispatch, no host round
     trip between slots.

@@ -20,8 +20,7 @@ from __future__ import annotations
 
 import functools
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.pipeline.graph import (default_app, get_graph_factory,
                                           graph_pallas, graph_ring_pallas,
                                           graph_stream_pallas)
@@ -41,9 +40,6 @@ __all__ = ["OUTPUTS", "canonical_outputs", "biosignal_pipeline",
            "ring_chunk_samples", "default_app"]
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
 
 def biosignal_pipeline(signal, taps, w, b, *, fft_size: int = 512,
                        block_rows: int | None = None,
@@ -62,7 +58,7 @@ def biosignal_pipeline(signal, taps, w, b, *, fft_size: int = 512,
     so winners are per-(shape, D).
     """
     outputs = canonical_outputs(outputs)
-    interpret = _interpret()
+    interpret = interpret_mode()
     run_cols = functools.partial(pipeline_sharded, n_columns=n_columns,
                                  mesh=mesh) if n_columns > 1 else \
         pipeline_pallas
@@ -103,7 +99,7 @@ def biosignal_pipeline_stream(signal, taps, w, b, *, window: int, hop: int,
     quantized share signature so winners don't leak across deal shapes.
     """
     outputs = canonical_outputs(outputs)
-    interpret = _interpret()
+    interpret = interpret_mode()
     assert column_weights is None or len(column_weights) == n_columns, \
         (column_weights, n_columns)
     if n_columns == 1:
@@ -146,7 +142,7 @@ def biosignal_pipeline_ring(ring, taps, w, b, *, window: int, hop: int,
     on `ring[r]`. See `docs/ARCHITECTURE.md` (serving control loop)."""
     outputs = canonical_outputs(outputs)
     return pipeline_ring_pallas(ring, taps, w, b, window=window, hop=hop,
-                                fft_size=fft_size, interpret=_interpret(),
+                                fft_size=fft_size, interpret=interpret_mode(),
                                 block_frames=block_frames, outputs=outputs)
 
 
@@ -161,7 +157,7 @@ def graph_pipeline(name: str, app, frames, *,
     factory = get_graph_factory(name)
     graph, operands = factory(app if app is not None
                               else default_app(name))
-    interpret = _interpret()
+    interpret = interpret_mode()
     if autotune and block_rows is None:
         from repro.core.autotune import tuned_block_rows
 
@@ -188,7 +184,7 @@ def graph_pipeline_stream(name: str, app, signal, *, window: int, hop: int,
     factory = get_graph_factory(name)
     graph, operands = factory(app if app is not None
                               else default_app(name))
-    interpret = _interpret()
+    interpret = interpret_mode()
     if autotune and block_frames is None:
         from repro.core.autotune import tuned_stream_block_frames
 
@@ -215,7 +211,7 @@ def graph_pipeline_ring(name: str, app, ring, *, window: int, hop: int,
     graph, operands = factory(app if app is not None
                               else default_app(name))
     return graph_ring_pallas(ring, operands, graph=graph, window=window,
-                             hop=hop, interpret=_interpret(),
+                             hop=hop, interpret=interpret_mode(),
                              block_frames=block_frames, outputs=outputs)
 
 

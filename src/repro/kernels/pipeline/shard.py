@@ -46,7 +46,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.pipeline.kernel import (OUTPUTS, canonical_outputs,
@@ -249,10 +248,10 @@ def _stream_shard_fn(mesh, window, hop, fft_size, interpret, block_frames,
     body = functools.partial(_stream_body, window=window, hop=hop,
                              fft_size=fft_size, interpret=interpret,
                              block_frames=block_frames, outputs=outputs)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"), P(), P(), P()),
         out_specs=P("data"),
-        check_rep=False))         # pallas_call has no replication rule
+        check_vma=False))         # pallas_call has no replication rule
 
 
 def pipeline_stream_sharded(signal, taps, w, b, *, window: int, hop: int,
@@ -341,10 +340,10 @@ def _framed_shard_fn(mesh, fft_size, interpret, block_rows, outputs):
     body = functools.partial(_framed_body, fft_size=fft_size,
                              interpret=interpret, block_rows=block_rows,
                              outputs=outputs)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"), P(), P(), P()),
         out_specs=P("data"),
-        check_rep=False))         # pallas_call has no replication rule
+        check_vma=False))         # pallas_call has no replication rule
 
 
 def pipeline_sharded(frames, taps, w, b, *, n_columns: int, mesh=None,

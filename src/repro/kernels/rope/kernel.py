@@ -1,10 +1,9 @@
 """Pallas TPU kernel: RoPE via the shuffle-unit dataflow (DESIGN.md §3).
 
-Interleaved (GPT-J) rotary IS the paper's shuffle algebra:
-    even/odd prune  ->  two streams x1, x2
-    rotate          ->  (x1 c - x2 s, x1 s + x2 c)     (VPU FMAs)
-    interleave      ->  back to lane-adjacent pairs
-The neox (rotate-half) layout replaces prune/interleave with half-splits.
+Interleaved (GPT-J) rotary IS the paper's shuffle algebra: every lane
+rotates with its pair partner, which a one-word circular shift brings
+alongside (x1 c - x2 s on even lanes, x2 c + x1 s on odd ones). The neox
+(rotate-half) layout splits halves instead.
 cos/sin are computed in-kernel from the staged position block (transcendental
 VPU ops) — no HBM-resident rotary table.
 """
@@ -25,21 +24,30 @@ def rope_kernel(x_ref, pos_ref, o_ref, *, theta: float, layout: str):
     x = x_ref[...].astype(jnp.float32)       # (rb, dh)
     pos = pos_ref[...].astype(jnp.float32)   # (rb, 1)
     dh = x.shape[-1]
-    # inv-freq built in-kernel (2D iota; no captured constants)
-    idx = jax.lax.broadcasted_iota(jnp.float32, (1, dh // 2), 1)
-    inv = jnp.exp(idx * (2.0 / dh) * (-np.log(theta)))
-    ang = pos * inv                          # (rb, dh/2)
-    c, s = jnp.cos(ang), jnp.sin(ang)
     if layout == "interleaved":
-        xp = x.reshape(x.shape[0], dh // 2, 2)
-        x1, x2 = xp[..., 0], xp[..., 1]      # even/odd prune
-        o1 = x1 * c - x2 * s
-        o2 = x1 * s + x2 * c
-        out = jnp.stack([o1, o2], axis=-1).reshape(x.shape)  # interleave
+        # lane l rotates with pair j = l // 2; the pair partner arrives by
+        # a lane rotate (Mosaic has no (rb, dh/2, 2) relayout):
+        # out[2j] = x1 c - x2 s, out[2j+1] = x2 c + x1 s
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, dh), 1)
+        c, s = _cos_sin(pos, lane >> 1, dh, theta)
+        even = (lane & 1) == 0
+        partner = jnp.where(even, -jnp.roll(x, -1, axis=-1),
+                            jnp.roll(x, 1, axis=-1))
+        out = x * c + partner * s
     else:
+        c, s = _cos_sin(pos, jax.lax.broadcasted_iota(
+            jnp.int32, (1, dh // 2), 1), dh, theta)
         x1, x2 = x[..., : dh // 2], x[..., dh // 2:]
         out = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
     o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _cos_sin(pos, pair, dh: int, theta: float):
+    """cos/sin of pos * inv_freq[pair], inv-freq built in-kernel from an
+    integer iota (no captured constants; Mosaic iotas are integer)."""
+    inv = jnp.exp(pair.astype(jnp.float32) * (2.0 / dh) * (-np.log(theta)))
+    ang = pos * inv
+    return jnp.cos(ang), jnp.sin(ang)
 
 
 @functools.partial(jax.jit, static_argnames=("theta", "layout", "interpret"))

@@ -3,11 +3,9 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.rope.kernel import rope_pallas
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def rope(x, positions, *, theta: float = 10000.0,
@@ -16,7 +14,7 @@ def rope(x, positions, *, theta: float = 10000.0,
     positions broadcastable to the row dims."""
     if x.ndim == 2:
         return rope_pallas(x, positions, theta=theta, layout=layout,
-                           interpret=_interpret())
+                           interpret=interpret_mode())
     shape = x.shape
     dh = shape[-1]
     rows = 1
@@ -26,5 +24,5 @@ def rope(x, positions, *, theta: float = 10000.0,
         positions[..., None] if positions.ndim == x.ndim - 2 else positions,
         shape[:-1]).reshape(rows)
     out = rope_pallas(x.reshape(rows, dh), pos, theta=theta, layout=layout,
-                      interpret=_interpret())
+                      interpret=interpret_mode())
     return out.reshape(shape)

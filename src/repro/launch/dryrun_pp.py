@@ -13,11 +13,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.hlo_cost import analyze
+from repro.launch.mesh import make_mesh
 from repro.sharding.pipeline import bubble_fraction, gpipe_apply
 
 
 def main():
-    mesh = jax.make_mesh((8, 64), ("pipe", "data"))
+    mesh = make_mesh((8, 64), ("pipe", "data"))
     d, d_ff = 1024, 2816                 # qwen1.5-0.5b-scale dense layer
     L, stages = 24, 8
     B, S = 256, 512                      # microbatched 8x inside the pipe

@@ -8,6 +8,15 @@ everything else sees the real (single-CPU) device set.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis `Auto`: the sharding code places
+    arrays with `with_sharding_constraint` and `NamedSharding`, which
+    JAX's default `Explicit` axes reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,14 +24,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     "pod" axis (2 pods = 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(*, data: int = 1, model: int = 1):
     """Tiny mesh over the actually-present devices (tests/examples)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -8,9 +8,11 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model, init_model_params
 from repro.serve.engine import Engine, Request
 
@@ -26,6 +28,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -39,14 +42,15 @@ def main():
     for rid in range(args.requests):
         plen = int(rng.integers(2, 8))
         prompt = rng.integers(1, cfg.vocab_size, size=plen).tolist()
-        eng.submit(Request(rid, prompt, max_new=args.max_new))
+        eng.add_request(Request(rid, prompt, max_new=args.max_new))
     done = eng.run_to_completion()
     dt = time.perf_counter() - t0
     tok = sum(len(r.out) for r in done)
     for r in sorted(done, key=lambda r: r.rid):
         print(f"req {r.rid}: prompt={r.prompt} -> {r.out}")
+    dev = jax.devices()[0]
     print(f"[serve] {len(done)} requests, {tok} tokens, "
-          f"{tok / dt:.1f} tok/s (CPU interpret)")
+          f"{tok / dt:.1f} tok/s ({dev.platform} {dev.device_kind})")
 
 
 if __name__ == "__main__":

@@ -220,7 +220,9 @@ class FaultTolerantColumnRunner:
             injector.clock is not None else time.perf_counter)
         self.telemetry = StreamTelemetry(clock=self.clock)
         if devices is None:
-            devices = [jax.devices()[0]] * n_columns
+            # distinct devices while they last; columns share beyond that
+            local = jax.devices()
+            devices = [local[d % len(local)] for d in range(n_columns)]
         self.scheduler = ColumnScheduler(
             devices, telemetry=self.telemetry,
             heartbeat_timeout=heartbeat_timeout, straggler=straggler,

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.kernels import interpret_mode
 from repro.core.biosignal import BiosignalApp, make_app
 from repro.kernels.pipeline.graph import (canonical_graph_outputs,
                                           get_graph_factory,
@@ -85,9 +86,6 @@ class ResidentConfig:
     drain_interval: int = 1
     autotune: bool = False
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -284,7 +282,7 @@ class ResidentStream:
                     window=cfg.window, hop=cfg.hop,
                     batch_windows=cfg.batch_windows,
                     ring_depth=ring_depth, n_sweeps=n_sweeps,
-                    interpret=_interpret(), block_frames=cfg.block_rows,
+                    interpret=interpret_mode(), block_frames=cfg.block_rows,
                     outputs=cfg.outputs)
         if self._retry is not None:
             return self._retry.call(dispatch)

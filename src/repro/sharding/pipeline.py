@@ -21,7 +21,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def gpipe_apply(layer_fn, stage_params, x, *, mesh, stage_axis: str = "pipe",
@@ -89,8 +88,8 @@ def gpipe_apply(layer_fn, stage_params, x, *, mesh, stage_axis: str = "pipe",
         x_spec,
     )
     out_specs = x_spec
-    fn = shard_map(stage_body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(stage_body, mesh=mesh, in_specs=in_specs,
+                   out_specs=out_specs, check_vma=False)
     return fn(stage_params, x)
 
 

@@ -75,8 +75,8 @@ def test_delineate_refractory_spacing():
 
 def test_network_sort_matches_np_sort():
     """Batcher odd-even merge network == np.sort for every power of two,
-    both the table-driven and the arithmetic (in-kernel fallback) forms."""
-    from repro.core.biosignal import _network_sort_arith, network_sort
+    in a leading-batch layout and a 1-D one."""
+    from repro.core.biosignal import network_sort
 
     rng = np.random.default_rng(0)
     for n in (1, 2, 4, 16, 128, 512):
@@ -84,8 +84,23 @@ def test_network_sort_matches_np_sort():
         want = np.sort(x, axis=-1)
         got = np.asarray(jax.jit(network_sort)(jnp.asarray(x)))
         np.testing.assert_array_equal(got, want)
-        got2 = np.asarray(jax.jit(_network_sort_arith)(jnp.asarray(x)))
-        np.testing.assert_array_equal(got2, want)
+        got1 = np.asarray(jax.jit(network_sort)(jnp.asarray(x[0])))
+        np.testing.assert_array_equal(got1, want[0])
+
+
+def test_log1p_within_two_ulps():
+    """The bit-level log1p the fused kernels use (Mosaic's own is off by
+    up to 2.6e-4 relative on v5e) stays within 2 ulps of float64 log1p
+    from 0 to 1e7, including the inputs fl(1 + x) rounds away."""
+    from repro.core.biosignal import log1p
+
+    x = np.concatenate([[0.0], np.logspace(-12, 7, 4096),
+                        np.linspace(0.0, 50.0, 4096)]).astype(np.float32)
+    want = np.log1p(x.astype(np.float64))
+    got = np.asarray(jax.jit(log1p)(jnp.asarray(x)), np.float64)
+    assert got[0] == 0.0
+    ulp = np.spacing(want[1:].astype(np.float32)).astype(np.float64)
+    assert float(np.max(np.abs(got[1:] - want[1:]) / ulp)) <= 2.0
 
 
 def test_masked_intervals_matches_sort_reference():

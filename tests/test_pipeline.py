@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.sharding.pipeline import bubble_fraction, gpipe_apply
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -19,7 +20,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.sharding.pipeline import gpipe_apply
 
-mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("pipe", "data"))
 rng = np.random.default_rng(0)
 L, S, d = 8, 4, 16            # 8 layers over 4 stages
 W = jnp.asarray(rng.normal(size=(L, d, d)).astype(np.float32) * 0.3)
@@ -64,7 +66,7 @@ def test_bubble_fraction():
 
 def test_gpipe_single_stage_identity(rng):
     """stages=1 degenerates to a plain scan (runs on the real 1-CPU mesh)."""
-    mesh = jax.make_mesh((1,), ("pipe",))
+    mesh = make_mesh((1,), ("pipe",))
     W = jnp.asarray(rng.normal(size=(4, 8, 8)).astype(np.float32) * 0.3)
     x = jnp.asarray(rng.normal(size=(4, 8)).astype(np.float32))
 

@@ -6,6 +6,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.hlo_cost import analyze, replica_groups, type_bytes
+from repro.launch.mesh import make_mesh
 from repro.sharding.rules import Strategy, spec_for
 
 
@@ -157,7 +158,7 @@ def test_activation_specs_strategies():
     import jax
     from repro.sharding.ctx import make_activation_specs
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tp = make_activation_specs(mesh, "train")
     assert tp["btd"].spec == P("data", None, None)
     assert tp["btv"].spec == P("data", None, "model")

@@ -69,3 +69,23 @@ def test_prune_keeps_survivors(rng):
     out = prune(a, b, drop="even")
     np.testing.assert_array_equal(np.asarray(out[:8]), np.asarray(a[1::2]))
     np.testing.assert_array_equal(np.asarray(out[8:]), np.asarray(b[1::2]))
+
+
+@pytest.mark.parametrize("n", [2, 8, 128, 512, 640])
+def test_rotate_forms_match_strided_indexing(n, rng):
+    """The rotate + select forms the Mosaic kernels use are exact
+    permutations: even-index compaction, deinterleave and (for powers of
+    two) the bit-reversal shuffle equal plain strided/gather indexing."""
+    from repro.core.shuffle import (bit_reverse_indices, bit_reverse_lanes,
+                                    compact_even)
+
+    x = jnp.asarray(rng.standard_normal((3, n)).astype(np.float32))
+    xn = np.asarray(x)
+    np.testing.assert_array_equal(np.asarray(compact_even(x))[:, : n // 2],
+                                  xn[:, 0::2])
+    ev, od = deinterleave(x)
+    np.testing.assert_array_equal(np.asarray(ev), xn[:, 0::2])
+    np.testing.assert_array_equal(np.asarray(od), xn[:, 1::2])
+    if n & (n - 1) == 0:
+        np.testing.assert_array_equal(np.asarray(bit_reverse_lanes(x)),
+                                      xn[:, bit_reverse_indices(n)])

@@ -10,6 +10,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import ckpt
 from repro.data.pipeline import DataConfig, ShardedLoader
+from repro.launch.mesh import make_mesh
 from repro.runtime.fault import (HeartbeatMonitor, StragglerDetector,
                                  Supervisor, elastic_plan)
 from repro.train import optim
@@ -100,7 +101,7 @@ def test_checkpoint_async_and_reshard():
         state = {"w": jnp.arange(64.0).reshape(8, 8)}
         _, t = ckpt.save(state, 1, d, async_write=True)
         t.join()
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         sh = {"w": jax.NamedSharding(mesh, jax.sharding.PartitionSpec(
             "data", None))}
         out = ckpt.restore(d, 1, state, sh)
@@ -199,14 +200,13 @@ def test_supervisor_recovers_from_failures():
 def test_psum_compressed_shard_map(rng):
     """Compressed all-reduce building block under shard_map (1 device)."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.train.compress import psum_compressed
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     x = jnp.asarray(rng.normal(size=(64,)).astype(np.float32))
-    f = shard_map(lambda v: psum_compressed(v, "pod"), mesh=mesh,
-                  in_specs=P(), out_specs=P(), check_rep=False)
+    f = jax.shard_map(lambda v: psum_compressed(v, "pod"), mesh=mesh,
+                      in_specs=P(), out_specs=P(), check_vma=False)
     with mesh:
         y = f(x)
     # single member: psum is identity up to int8 quantization error
