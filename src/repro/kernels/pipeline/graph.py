@@ -39,6 +39,9 @@ Invariants (pinned by `tests/test_stage_graph.py` / `tests/test_asr.py`):
   outputs are never written to HBM (their out specs don't exist). This
   strictly generalizes the old ``outputs != ("filtered",)`` special
   case.
+* **Stable names.** Each entry names its `pallas_call`
+  ``graph_<mode>_kernel.<graph name>`` (`kernel_name`: modes ``frames``,
+  ``stream``, ``ring``), whichever function jits it.
 
 The biosignal graph is registered by `kernel.py` (name ``"biosignal"``),
 the ASR front-end by `asr.py` (name ``"asr"``); `get_graph_factory`
@@ -481,6 +484,14 @@ def _graph_as_output_dict(graph: StageGraph, outs: tuple, outputs: tuple,
             for o, v in zip(outputs, outs)}
 
 
+def kernel_name(mode: str, graph: StageGraph) -> str:
+    """The `pallas_call` name of a graph's ``mode`` entry (``frames``,
+    ``stream`` or ``ring``), e.g. ``graph_stream_kernel.biosignal``. It
+    is the Mosaic kernel's name and a scope of the call's op name in a
+    profiler trace, whatever function jits the entry."""
+    return f"graph_{mode}_kernel.{graph.name}"
+
+
 def graph_frames_call(frames, operands, *, graph: StageGraph,
                       interpret: bool = True,
                       block_rows: int | None = None, outputs=None):
@@ -504,6 +515,7 @@ def graph_frames_call(frames, operands, *, graph: StageGraph,
         out_specs=out_specs,
         grid=(R // rb,),
         interpret=interpret,
+        name=kernel_name("frames", graph),
     )(frames, *operands)
     return _graph_as_output_dict(graph, outs, outputs, R)
 
@@ -567,6 +579,7 @@ def graph_stream_call(signal, operands, *, graph: StageGraph, window: int,
         out_specs=out_specs,
         grid=(n_blocks,),
         interpret=interpret,
+        name=kernel_name("stream", graph),
     )(*((sig.reshape(rows, hop),) * (1 + n_tails)), *operands)
     return _graph_as_output_dict(graph, outs, outputs, n)
 
@@ -608,6 +621,7 @@ def graph_ring_call(ring, operands, *, graph: StageGraph, window: int,
         out_specs=out_specs,
         grid=(D, n_blocks),
         interpret=interpret,
+        name=kernel_name("ring", graph),
     )(*((ring.reshape(D, rows, hop),) * (1 + n_tails)), *operands)
     res = _graph_as_output_dict(graph, outs, outputs, D * n_blocks * rb)
     # per-slot trim: every slot framed n_blocks*rb rows, keep its n real

@@ -50,6 +50,11 @@ restores them (`ColumnScheduler.restore`). The supervision layers of
 PR 7/8 ride along unchanged underneath — the front-end is policy, the
 engines keep their own closed loops.
 
+TRACING — `submit` records a ``serve.submit`` span (``ticket``,
+``cls``) and each dispatch in `pump` a ``serve.admit`` span (``ticket``,
+``cls``, and for the stream class ``stream``, the id the stream's own
+``serve.process`` spans carry); see `serve/trace.py`.
+
 See `docs/ARCHITECTURE.md` (unified admission) for where this sits in
 the serving-runtime map.
 """
@@ -61,6 +66,7 @@ from typing import Optional
 from repro.serve.engine import Request
 from repro.serve.errors import (QueueFull, RequestExpired, ServeError,
                                 TicketNotReady)
+from repro.serve.trace import span
 
 __all__ = ["StreamOpen", "AsrTranscribe", "AsrResult", "Ticket",
            "ServeFrontend"]
@@ -186,22 +192,27 @@ class ServeFrontend:
             raise TypeError(
                 f"submit() takes a Request, a StreamOpen, or an "
                 f"AsrTranscribe, got {type(work).__name__}")
-        t = Ticket(len(self.tickets), cls)
-        self.tickets.append(t)
-        self._pending.append((t, work, kwargs))
+        tid = len(self.tickets)
+        with span("submit", ticket=tid, cls=cls):
+            t = Ticket(tid, cls)
+            self.tickets.append(t)
+            self._pending.append((t, work, kwargs))
         return t
 
     def _dispatch(self, ticket: Ticket, work, kwargs) -> None:
-        if ticket.work_class == "lm":
-            self.engine.add_request(work, **kwargs)
-            self._by_rid[work.rid] = ticket
-            ticket.status = "running"
-        elif ticket.work_class == "asr":
-            self._dispatch_asr(ticket, work, kwargs)
-        else:
-            stream = self.scheduler.place_stream(
-                work.app, work.cfg, stream_id=work.stream_id, **kwargs)
-            ticket._finish(stream)
+        cls = ticket.work_class
+        meta = {"stream": work.stream_id} if cls == "stream" else {}
+        with span("admit", ticket=ticket.tid, cls=cls, **meta):
+            if cls == "lm":
+                self.engine.add_request(work, **kwargs)
+                self._by_rid[work.rid] = ticket
+                ticket.status = "running"
+            elif cls == "asr":
+                self._dispatch_asr(ticket, work, kwargs)
+            else:
+                stream = self.scheduler.place_stream(
+                    work.app, work.cfg, stream_id=work.stream_id, **kwargs)
+                ticket._finish(stream)
 
     def _dispatch_asr(self, ticket: Ticket, work: AsrTranscribe,
                       kwargs) -> None:

@@ -73,6 +73,7 @@ from repro.kernels.pipeline.ops import (OUTPUTS, app_pipeline,
                                         graph_pipeline,
                                         graph_pipeline_stream,
                                         stream_frame_count)
+from repro.serve.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -305,8 +306,9 @@ class BiosignalStream:
     device-resident mode (`process_resident`); the zero-frame degenerate
     path returns the same keys/dtypes as the hot path. The control-loop
     structure (what runs on host vs device) is diagrammed in
-    `docs/ARCHITECTURE.md`; the CI gates pinning the speedups are in
-    `docs/BENCHMARKS.md`.
+    `docs/ARCHITECTURE.md`, with the ``serve.*`` profiler spans this loop
+    records (Tracing). `docs/BENCHMARKS.md` holds CPU interpret-mode
+    gates, which time no chip; chip numbers are in `PERF.md`.
     """
 
     def __init__(self, app: BiosignalApp | None = None,
@@ -409,11 +411,11 @@ class BiosignalStream:
                 self.injector.on_dispatch(self.column)
             if self._graph is not None:
                 return graph_pipeline_stream(
-                    cfg.graph, self.app, self._place(chunk),
+                    cfg.graph, self.app, chunk,
                     window=cfg.window, hop=cfg.hop,
                     block_frames=cfg.block_rows, autotune=cfg.autotune,
                     outputs=cfg.outputs)
-            return app_pipeline_stream(self.app, self._place(chunk),
+            return app_pipeline_stream(self.app, chunk,
                                        window=cfg.window, hop=cfg.hop,
                                        block_frames=cfg.block_rows,
                                        autotune=cfg.autotune,
@@ -428,46 +430,61 @@ class BiosignalStream:
     def _dispatch_frames(self, frames):
         """Pre-framed dispatch (fallback/reference path)."""
         if self._graph is not None:
-            return graph_pipeline(self.cfg.graph, self.app,
-                                  self._place(frames),
+            return graph_pipeline(self.cfg.graph, self.app, frames,
                                   block_rows=self.cfg.block_rows,
                                   autotune=self.cfg.autotune,
                                   outputs=self.cfg.outputs)
-        return app_pipeline(self.app, self._place(frames),
+        return app_pipeline(self.app, frames,
                             block_rows=self.cfg.block_rows,
                             autotune=self.cfg.autotune,
                             outputs=self.cfg.outputs,
                             n_columns=self.cfg.n_columns, mesh=self.mesh)
 
     def _batches(self, signal) -> Iterator[tuple]:
-        """(in-flight output dict, n valid frames) per window batch."""
+        """(in-flight output dict, n valid frames, dispatch index k) per
+        window batch. Spans: ``serve.upload`` (the signal's host-to-device
+        copy), then per dispatch ``serve.prepare`` (slice, tail pad,
+        device placement) and ``serve.launch`` (enqueueing the fused
+        kernel, fault injector and retry included; ``valid`` real frames
+        of ``slots``)."""
         cfg = self.cfg
-        sig = jnp.asarray(signal)
+        with span("upload", stream=self.stream_id):
+            sig = jnp.asarray(signal)
         n = frame_count(sig.shape[0], cfg.window, cfg.hop)
         bw = self.dispatch_windows
         if cfg.framing == "host":
             frames = frame_signal(sig, cfg.window, cfg.hop)
-            for start in range(0, n, bw):
-                batch = frames[start: start + bw]
-                valid = batch.shape[0]
-                if valid < bw:      # pad the tail batch to the fixed shape
-                    batch = jnp.concatenate(
-                        [batch, jnp.zeros((bw - valid, cfg.window),
-                                          batch.dtype)], axis=0)
-                yield self._dispatch_frames(batch), valid
+            for k, start in enumerate(range(0, n, bw)):
+                with span("prepare", dispatch=k):
+                    batch = frames[start: start + bw]
+                    valid = batch.shape[0]
+                    if valid < bw:  # pad the tail batch to the fixed shape
+                        batch = jnp.concatenate(
+                            [batch, jnp.zeros((bw - valid, cfg.window),
+                                              batch.dtype)], axis=0)
+                    batch = self._place(batch)
+                with span("launch", dispatch=k, valid=valid, slots=bw):
+                    out = self._dispatch_frames(batch)
+                yield out, valid, k
             return
         # raw-chunk feed: batch k's frames live in one contiguous slice of
         # the signal — no gather, and the tail batch (frames % (bw*D) != 0)
         # pads with at most chunk_samples raw zeros instead of bw-valid
         # whole zero frames; the sharded entry trims the pad columns
-        span = self.chunk_samples
-        for start in range(0, n, bw):
-            s0 = start * cfg.hop
-            chunk = sig[s0: s0 + span]
-            if chunk.shape[0] < span:
-                chunk = jnp.concatenate(
-                    [chunk, jnp.zeros((span - chunk.shape[0],), sig.dtype)])
-            yield self._dispatch_chunk(chunk), min(bw, n - start)
+        width = self.chunk_samples
+        for k, start in enumerate(range(0, n, bw)):
+            valid = min(bw, n - start)
+            with span("prepare", dispatch=k):
+                s0 = start * cfg.hop
+                chunk = sig[s0: s0 + width]
+                if chunk.shape[0] < width:
+                    chunk = jnp.concatenate(
+                        [chunk, jnp.zeros((width - chunk.shape[0],),
+                                          sig.dtype)])
+                chunk = self._place(chunk)
+            with span("launch", dispatch=k, valid=valid, slots=bw):
+                out = self._dispatch_chunk(chunk)
+            yield out, valid, k
 
     def stream(self, signal) -> Iterator[dict]:
         """Yields one output dict per window batch (trimmed to the real
@@ -475,7 +492,7 @@ class BiosignalStream:
         batch k is yielded, so the consumer always overlaps with
         `depth` in-flight batches (depth=1 is the classic double buffer:
         consume k while k+1 runs)."""
-        inflight: deque[tuple[dict, int]] = deque()
+        inflight: deque[tuple[dict, int, int]] = deque()
         for nxt in self._batches(signal):       # async: in flight now
             inflight.append(nxt)
             if len(inflight) > self.cfg.depth:
@@ -483,11 +500,16 @@ class BiosignalStream:
         while inflight:
             yield self._collect(*inflight.popleft())
 
-    def _collect(self, out: dict, valid: int) -> dict:
-        out = jax.block_until_ready(out)        # the batch retires HERE
-        if self.telemetry is not None:
-            self.telemetry.record_retire(self.stream_id, valid)
-        return {k: v[:valid] for k, v in out.items()}
+    def _collect(self, out: dict, valid: int, k: int) -> dict:
+        """Retire dispatch ``k`` (span ``serve.retire``): wait for its
+        outputs (``serve.wait``), report the retire, trim the pad
+        frames."""
+        with span("retire", dispatch=k, valid=valid):
+            with span("wait", dispatch=k):
+                out = jax.block_until_ready(out)    # the batch retires HERE
+            if self.telemetry is not None:
+                self.telemetry.record_retire(self.stream_id, valid)
+            return {key: v[:valid] for key, v in out.items()}
 
     def _empty(self, dtype) -> dict:
         """Zero-frame result: same keys/shapes/dtypes as the kernel path."""
@@ -500,12 +522,16 @@ class BiosignalStream:
 
     def process(self, signal) -> dict:
         """One-call convenience: all framed outputs concatenated, equal to
-        running the app on `frame_signal(signal, window, hop)` at once."""
-        chunks = list(self.stream(signal))
-        if not chunks:
-            return self._empty(jnp.asarray(signal).dtype)
-        return {k: jnp.concatenate([c[k] for c in chunks], axis=0)
-                for k in chunks[0]}
+        running the app on `frame_signal(signal, window, hop)` at once.
+        Spans: ``serve.process`` around the call, ``serve.concat`` around
+        assembling the result."""
+        with span("process", stream=self.stream_id, samples=len(signal)):
+            chunks = list(self.stream(signal))
+            if not chunks:
+                return self._empty(jnp.asarray(signal).dtype)
+            with span("concat", stream=self.stream_id):
+                return {k: jnp.concatenate([c[k] for c in chunks], axis=0)
+                        for k in chunks[0]}
 
     def process_resident(self, signal, rcfg=None) -> dict:
         """`process`, but with the steady-state loop ON-DEVICE: delegates
