@@ -31,29 +31,39 @@ streams can instead be pinned to distinct columns via ``device=`` — that is
 what `serve.engine.ColumnScheduler` hands out.
 
 TELEMETRY: `StreamTelemetry` measures per-stream and per-column throughput
-(an EWMA of windows/s, updated on every batch retire — the moment
-`_collect` blocks until a dispatch's outputs are ready). The measurements
-are what make the runtime LOAD-AWARE: `serve.engine.ColumnScheduler`
-places new streams on the column with the least measured load (not just
-the fewest streams), its `rebalance` step re-pins streams when the
+(an EWMA of windows/s, updated on every retire — the moment `_collect`,
+or the per-upload loop, blocks until the dispatched outputs are ready).
+The measurements are what make the runtime LOAD-AWARE:
+`serve.engine.ColumnScheduler` places new streams on the column with the
+least measured load (not just the fewest streams), its `rebalance` step
+re-pins streams when the
 max/min column-load ratio blows past a threshold, and `deal_weights`
 turns measured per-column rates into the non-uniform `column_shares`
 deal (`StreamConfig.column_weights`) — a column sharing its device with
 another tenant retires slower, so it is dealt proportionally fewer
 frames.
 
-DEVICE-RESIDENT MODE: this module's dispatch loop is host-driven — one
-Python round trip per batch, kept as the REFERENCE path. The steady-state
-sibling lives in `serve/resident.py` (`ResidentStream`, reachable from
-here via `BiosignalStream.process_resident`): a `lax.scan` iterates ring
-sweeps of the donated signal buffer inside one compiled computation and
-drains the retire counters into the same `StreamTelemetry` at a low,
-configurable frequency. Outputs are bit-identical to this path.
-`docs/ARCHITECTURE.md` shows both control loops side by side.
+PER-UPLOAD LOOP: `BiosignalStream.process` on a raw-chunk (framing
+"kernel"), single-column stream runs the whole upload as ONE jitted
+program (`_upload_loop`): a `lax.scan` over the upload's dispatches, each
+step a hop-aligned slice of the device-resident signal through the same
+stream kernel a per-batch dispatch launches. The host pays one upload,
+one launch and one wait per upload instead of a Python round trip per
+batch. `stream()`, `framing="host"` and ``n_columns > 1`` keep the
+host-driven per-batch loop (`_batches`), the REFERENCE path.
+
+DEVICE-RESIDENT MODE: the steady-state sibling lives in
+`serve/resident.py` (`ResidentStream`, reachable from here via
+`BiosignalStream.process_resident`): a `lax.scan` iterates ring sweeps of
+the donated signal buffer inside one compiled computation and drains the
+retire counters into the same `StreamTelemetry` at a low, configurable
+frequency. Outputs are bit-identical to this path.
+`docs/ARCHITECTURE.md` shows the control loops side by side.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Iterator
@@ -61,11 +71,16 @@ from typing import Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.biosignal import BiosignalApp, make_app
+from repro.kernels import interpret_mode
 from repro.kernels.pipeline.graph import (canonical_graph_outputs,
                                           get_graph_factory,
-                                          graph_empty_outputs)
+                                          graph_empty_outputs,
+                                          graph_stream_call,
+                                          graph_stream_pallas,
+                                          ring_chunk_samples)
 from repro.kernels.pipeline.kernel import empty_outputs
 from repro.kernels.pipeline.ops import (OUTPUTS, app_pipeline,
                                         app_pipeline_stream,
@@ -146,6 +161,41 @@ def column_mesh(n_columns: int):
     from repro.launch.mesh import make_local_mesh
 
     return make_local_mesh(data=n_columns)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("graph", "window", "hop", "batch_windows", "interpret",
+                     "block_frames", "outputs"))
+def _upload_loop(sig, operands, *, graph, window: int, hop: int,
+                 batch_windows: int, interpret: bool,
+                 block_frames: int | None, outputs: tuple):
+    """A whole upload's dispatches as ONE compiled program: the signal is
+    padded once with the raw zeros the per-batch tail chunk gets, then a
+    `lax.scan` over the ``n_batches`` dispatch indices slices chunk k at
+    ``k * batch_windows * hop`` and runs it through `graph_stream_call`
+    — one stream-kernel launch per dispatch, the very call (and chunk)
+    the per-batch path makes, so the outputs are bit-identical. Returns
+    the frame-major output dict trimmed to the signal's frames (>= 1)."""
+    n = frame_count(sig.shape[0], window, hop)
+    n_batches = -(-n // batch_windows)
+    stride = batch_windows * hop
+    width = ring_chunk_samples(window, hop, batch_windows)
+    total = (n_batches - 1) * stride + width
+    sig = sig[:min(sig.shape[0], total)]
+    if total > sig.shape[0]:
+        sig = jnp.concatenate(
+            [sig, jnp.zeros((total - sig.shape[0],), sig.dtype)])
+
+    def dispatch(carry, k):
+        chunk = lax.dynamic_slice(sig, (k * stride,), (width,))
+        return carry, graph_stream_call(
+            chunk, operands, graph=graph, window=window, hop=hop,
+            interpret=interpret, block_frames=block_frames, outputs=outputs)
+
+    _, outs = lax.scan(dispatch, None, jnp.arange(n_batches))
+    return {k: v.reshape((n_batches * batch_windows,) + v.shape[2:])[:n]
+            for k, v in outs.items()}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,7 +330,9 @@ class StreamTelemetry:
 
 class BiosignalStream:
     """Drives a continuous signal through the fused pipeline kernel in
-    pipelined window batches (up to `cfg.depth` in flight).
+    window batches: `stream` yields them one by one (up to `cfg.depth` in
+    flight); `process` on a raw-chunk, single-column stream runs all of
+    an upload's batches in one on-device loop.
 
     >>> stream = BiosignalStream(make_app(), StreamConfig(hop=256))
     >>> out = stream.process(signal)          # dict over all frames
@@ -291,7 +343,8 @@ class BiosignalStream:
     each dispatch of one stream across all columns).
 
     ``telemetry`` (a `StreamTelemetry`) makes the stream report every
-    batch retire under ``stream_id`` on ``column`` — the measurements the
+    retire (a batch in `stream`, a whole upload in the per-upload loop)
+    under ``stream_id`` on ``column`` — the measurements the
     load-aware scheduler places and rebalances on. `repin` moves the
     stream to another device mid-flight (a `ColumnScheduler.rebalance`
     move); in-flight batches finish on the old device, later dispatches
@@ -463,7 +516,8 @@ class BiosignalStream:
                             [batch, jnp.zeros((bw - valid, cfg.window),
                                               batch.dtype)], axis=0)
                     batch = self._place(batch)
-                with span("launch", dispatch=k, valid=valid, slots=bw):
+                with span("launch", dispatch=k, valid=valid, slots=bw,
+                          batches=1):
                     out = self._dispatch_frames(batch)
                 yield out, valid, k
             return
@@ -482,7 +536,8 @@ class BiosignalStream:
                         [chunk, jnp.zeros((width - chunk.shape[0],),
                                           sig.dtype)])
                 chunk = self._place(chunk)
-            with span("launch", dispatch=k, valid=valid, slots=bw):
+            with span("launch", dispatch=k, valid=valid, slots=bw,
+                      batches=1):
                 out = self._dispatch_chunk(chunk)
             yield out, valid, k
 
@@ -520,12 +575,77 @@ class BiosignalStream:
         return empty_outputs(self.cfg.window, w[0], w[1], dtype,
                              self.cfg.outputs)
 
+    @functools.cached_property
+    def _staged(self) -> tuple:
+        """(StageGraph, operand tables) the per-upload loop runs."""
+        return get_graph_factory(self.cfg.graph)(self.app)
+
+    def _loop_block_frames(self, dtype) -> int | None:
+        """The frame-block a per-batch dispatch would use: the pinned
+        ``block_rows``, or under ``autotune`` the tuner's winner for one
+        dispatch's chunk (same cache key as the per-batch entry, measured
+        on a zero chunk when not cached yet)."""
+        cfg = self.cfg
+        if not cfg.autotune or cfg.block_rows is not None or \
+                cfg.batch_windows == 1:
+            return cfg.block_rows
+        from repro.core.autotune import tuned_stream_block_frames
+
+        graph, operands = self._staged
+        chunk = jnp.zeros((self.chunk_samples,), dtype)
+        return tuned_stream_block_frames(
+            f"{graph.name}_pipeline_stream", cfg.batch_windows, cfg.window,
+            cfg.hop, cfg.outputs, str(dtype),
+            lambda rb: graph_stream_pallas(
+                chunk, operands, graph=graph, window=cfg.window, hop=cfg.hop,
+                interpret=interpret_mode(), block_frames=rb,
+                outputs=cfg.outputs))
+
+    def _process_upload(self, signal) -> dict:
+        """`process` on a raw-chunk, single-column stream: every dispatch
+        of the upload in ONE on-device loop (`_upload_loop`). The fault
+        injector fires once per dispatch, in order and each through the
+        retry, before the program runs; the telemetry sees one retire of
+        all ``n`` frames. Spans: ``serve.prepare`` (the signal's copy to
+        the stream's device), one ``serve.launch`` (``valid`` frames of
+        ``slots``, over ``batches`` dispatches), ``serve.retire`` around
+        ``serve.wait``."""
+        cfg = self.cfg
+        with span("prepare", dispatch=0):
+            sig = jax.device_put(signal, self.device)
+        assert sig.ndim == 1, sig.shape
+        n = frame_count(sig.shape[0], cfg.window, cfg.hop)
+        if n == 0:
+            return self._empty(sig.dtype)
+        batches = -(-n // cfg.batch_windows)
+        graph, operands = self._staged
+        with span("launch", dispatch=0, valid=n,
+                  slots=batches * cfg.batch_windows, batches=batches):
+            if self.injector is not None:
+                for _ in range(batches):
+                    self._retry.call(self.injector.on_dispatch, self.column)
+            out = _upload_loop(
+                sig, operands, graph=graph, window=cfg.window, hop=cfg.hop,
+                batch_windows=cfg.batch_windows, interpret=interpret_mode(),
+                block_frames=self._loop_block_frames(sig.dtype),
+                outputs=cfg.outputs)
+        with span("retire", dispatch=0, valid=n):
+            with span("wait", dispatch=0):
+                out = jax.block_until_ready(out)
+            if self.telemetry is not None:
+                self.telemetry.record_retire(self.stream_id, n)
+        return out
+
     def process(self, signal) -> dict:
-        """One-call convenience: all framed outputs concatenated, equal to
-        running the app on `frame_signal(signal, window, hop)` at once.
-        Spans: ``serve.process`` around the call, ``serve.concat`` around
-        assembling the result."""
+        """One-call convenience: all framed outputs, equal to running the
+        app on `frame_signal(signal, window, hop)` at once. A raw-chunk,
+        single-column stream runs the upload as one on-device loop
+        (`_process_upload`); the others concatenate `stream`'s batches.
+        Spans: ``serve.process`` around the call, and on the per-batch
+        path ``serve.concat`` around assembling the result."""
         with span("process", stream=self.stream_id, samples=len(signal)):
+            if self.cfg.framing == "kernel" and self.cfg.n_columns == 1:
+                return self._process_upload(signal)
             chunks = list(self.stream(signal))
             if not chunks:
                 return self._empty(jnp.asarray(signal).dtype)

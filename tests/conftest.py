@@ -1,3 +1,4 @@
+import jax
 import numpy as np
 import pytest
 
@@ -17,3 +18,15 @@ except ModuleNotFoundError:
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """Drop JAX's compiled programs after each test module. On the CPU a
+    program holds over a thousand memory mappings (an interpret-mode
+    kernel inside `BiosignalStream.process`'s per-upload loop, compiled
+    once per upload length), and a test worker that keeps every module's
+    programs passes the kernel's limit (`vm.max_map_count`, 65530) and
+    dies inside the compiler."""
+    yield
+    jax.clear_caches()

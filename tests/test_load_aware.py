@@ -196,8 +196,9 @@ def test_telemetry_ewma_math_and_column_aggregation():
 
 
 def test_stream_reports_retires_to_telemetry():
-    """The runtime integration: every processed batch retires through the
-    telemetry under the stream's id/column."""
+    """The runtime integration: every processed upload retires its frames
+    through the telemetry under the stream's id/column (one retire a
+    call on the per-upload loop, so a rate needs two calls)."""
     app = make_app()
     tel = StreamTelemetry()
     sig, _ = synthetic_respiration(1, 512 * 10 + 3, seed=17)
@@ -207,10 +208,13 @@ def test_stream_reports_retires_to_telemetry():
                              column=2)
     n = frame_count(raw.shape[0], 512, 256)
     stream.process(raw)
+    assert tel.column_stats(3)[2].windows == n
+    assert not tel.warm                 # one retire seeds the clock only
+    stream.process(raw)
     stats = tel.column_stats(3)
-    assert stats[2].windows == n
+    assert stats[2].windows == 2 * n
     assert stats[2].streams == 1
-    assert tel.warm                     # >= 2 batches retired -> real rate
+    assert tel.warm                     # >= 2 retires -> real rate
     assert tel.stream_rate("s0") > 0.0
 
 

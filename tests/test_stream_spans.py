@@ -3,11 +3,13 @@
 `BiosignalStream.process` and `ServeFrontend` record ``serve.*``
 `jax.profiler.TraceAnnotation` spans. Each case here runs the path under a
 `jax.profiler` session on the CPU and reads the ``.xplane.pb`` it wrote
-with `jax.profiler.ProfileData`: one ``serve.process`` per call, one
-``serve.launch`` per dispatch carrying its real frames (``valid``) and its
-slots, ``prepare``/``launch``/``retire`` tied by ``dispatch``, each
-``serve.wait`` inside its ``serve.retire``, and an admission span that
-ties a ticket to the stream it opened. The outputs do not depend on a
+with `jax.profiler.ProfileData`: one ``serve.process`` per call; in the
+per-batch loop one ``serve.launch`` per dispatch carrying its real frames
+(``valid``), its slots and ``batches=1``, ``prepare``/``launch``/
+``retire`` tied by ``dispatch``, each ``serve.wait`` inside its
+``serve.retire``; in the per-upload loop one of each, the launch carrying
+every batch of the upload; and an admission span that ties a ticket to
+the stream it opened. The outputs do not depend on a
 session being active.
 """
 from pathlib import Path
@@ -71,6 +73,10 @@ def _inside(inner, outer):
 
 @pytest.mark.parametrize("framing", ["kernel", "host"])
 def test_process_records_one_span_tree(app, signal, framing, tmp_path):
+    """`process`: one ``serve.process`` around the call. Host framing runs
+    the per-batch loop (an upload, a launch of one batch per dispatch, a
+    concatenation); raw chunks run the per-upload loop (one prepare, one
+    launch of every batch, one retire around one wait)."""
     stream = BiosignalStream(app, _cfg(framing), stream_id="rec-7")
     stream.process(signal)                      # compile outside the trace
     out, ev = _traced(tmp_path, lambda: stream.process(signal))
@@ -78,22 +84,58 @@ def test_process_records_one_span_tree(app, signal, framing, tmp_path):
 
     (proc,) = _named(ev, "process")
     assert proc[3] == {"stream": "rec-7", "samples": signal.shape[0]}
-    for name in ("upload", "concat"):
-        (sp,) = _named(ev, name)
-        assert sp[3] == {"stream": "rec-7"} and _inside(sp, proc)
+    assert all(_inside(sp, proc) for sp in ev if sp is not proc)
+    k = -(-N_FRAMES // BW)
+    launches = _named(ev, "launch")
+    assert sum(sp[3]["valid"] for sp in launches) == N_FRAMES
+    assert sum(sp[3]["slots"] for sp in launches) == k * BW
+    assert sum(sp[3]["batches"] for sp in launches) == k
+    if framing == "host":
+        for name in ("upload", "concat"):
+            (sp,) = _named(ev, name)
+            assert sp[3] == {"stream": "rec-7"}
+        assert len(launches) == k
+        assert all(sp[3]["batches"] == 1 for sp in launches)
+        return
+    assert not _named(ev, "upload") and not _named(ev, "concat")
+    (prepare,) = _named(ev, "prepare")
+    (launch,) = launches
+    (retire,) = _named(ev, "retire")
+    (wait,) = _named(ev, "wait")
+    assert launch[3] == {"dispatch": 0, "valid": N_FRAMES, "slots": k * BW,
+                         "batches": k}
+    assert prepare[3] == {"dispatch": 0} and wait[3] == {"dispatch": 0}
+    assert retire[3] == {"dispatch": 0, "valid": N_FRAMES}
+    assert prepare[2] <= launch[1] and launch[2] <= retire[1]
+    assert _inside(wait, retire)
+
+
+@pytest.mark.parametrize("framing", ["kernel", "host"])
+def test_dispatch_spans_tie_prepare_launch_retire(app, signal, framing,
+                                                  tmp_path):
+    """The per-batch loop (`stream`): one ``serve.launch`` of one batch per
+    dispatch carrying its real frames and its slots, ``prepare`` /
+    ``launch`` / ``retire`` tied by ``dispatch``, each ``serve.wait``
+    inside its ``serve.retire``."""
+    stream = BiosignalStream(app, _cfg(framing), stream_id="rec-7")
+    list(stream.stream(signal))                 # compile outside the trace
+    out, ev = _traced(tmp_path, lambda: list(stream.stream(signal)))
+    assert sum(b["class"].shape[0] for b in out) == N_FRAMES
+    (upload,) = _named(ev, "upload")
+    assert upload[3] == {"stream": "rec-7"}
 
     launches = _named(ev, "launch")
     k = -(-N_FRAMES // BW)
     assert len(launches) == k
     assert sum(sp[3]["valid"] for sp in launches) == N_FRAMES
     assert all(sp[3]["slots"] == BW for sp in launches)
+    assert all(sp[3]["batches"] == 1 for sp in launches)
     assert [sp[3]["valid"] for sp in launches][-1] == N_FRAMES % BW
 
     ids = list(range(k))
     for name in ("prepare", "launch", "retire", "wait"):
         spans = _named(ev, name)
         assert sorted(sp[3]["dispatch"] for sp in spans) == ids, name
-        assert all(_inside(sp, proc) for sp in spans), name
     retire = {sp[3]["dispatch"]: sp for sp in _named(ev, "retire")}
     assert {d: sp[3]["valid"] for d, sp in retire.items()} == \
         {sp[3]["dispatch"]: sp[3]["valid"] for sp in launches}
@@ -138,4 +180,5 @@ def test_admission_ties_the_ticket_to_the_stream(app, signal, tmp_path):
     assert proc[3]["stream"] == admit[3]["stream"]
     assert admit[2] <= proc[1]
     (launch,) = _named(ev, "launch")
-    assert (launch[3]["valid"], launch[3]["slots"]) == (BW, BW)
+    assert (launch[3]["valid"], launch[3]["slots"],
+            launch[3]["batches"]) == (BW, BW, 1)

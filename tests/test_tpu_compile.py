@@ -3,8 +3,9 @@
 The TPU compiler is installed even where no chip is attached, so each test
 lowers a kernel with ``interpret=False`` against a described ``v5e:2x2``
 topology and compiles it for one of its chips at the real widths: the
-biosignal graph at 2048 / 512, the ASR graph at 512 / 128, and the
-standalone FIR, FFT and RoPE kernels. A compile that passes says the
+biosignal graph at 2048 / 512 (also inside the stream's per-upload loop
+at the archive length), the ASR graph at 512 / 128, and the standalone
+FIR, FFT and RoPE kernels. A compile that passes says the
 kernel is Mosaic-legal (tiling, VMEM, lowering rules); it runs nothing.
 Each compiled program must hold the Mosaic kernel (``tpu_custom_call``).
 
@@ -12,6 +13,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from repro.kernels.pipeline.graph import (default_app, get_graph_factory,
                                           graph_stream_pallas,
                                           ring_chunk_samples)
 from repro.kernels.rope.kernel import rope_pallas
+from repro.serve.stream import _upload_loop
 
 GRAPHS = {"biosignal": (2048, 512), "asr": (512, 128)}
 
@@ -85,6 +88,31 @@ def test_biosignal_ring_compiles(one_chip):
     _assert_mosaic(lambda x, *o: graph_ring_pallas(
         x, o, graph=graph, window=window, hop=hop, interpret=False),
         _shape((4, span), one_chip), *ops)
+
+
+def test_biosignal_upload_loop_compiles(one_chip):
+    """`BiosignalStream.process`'s per-upload program at the archive
+    length (8 h at 64 Hz): the stream kernel runs inside the loop's body,
+    under the name the device trace gives its launches."""
+    window, hop = GRAPHS["biosignal"]
+    graph, ops = _graph("biosignal", one_chip)
+    text = jax.jit(lambda x, *o: _upload_loop(
+        x, o, graph=graph, window=window, hop=hop, batch_windows=64,
+        interpret=False, block_frames=None,
+        outputs=("features", "margin", "class"))).lower(
+        _shape((1_843_200,), one_chip), *ops).compile().as_text()
+    comps = {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \(.*?^}", text, re.M | re.S)}
+    (body,) = re.findall(r"body=%([\w.\-]+)", text)
+    reached, todo = set(), [body]
+    while todo:
+        name = todo.pop()
+        if name not in reached:
+            reached.add(name)
+            todo += re.findall(r"calls=%([\w.\-]+)", comps[name])
+    assert any("tpu_custom_call" in comps[c] for c in reached)
+    assert re.search(r"^\s*%graph_stream_kernel\.biosignal[.\d]* = ",
+                     comps[body], re.M)
 
 
 def test_fir_kernel_compiles(one_chip):
