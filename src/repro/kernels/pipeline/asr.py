@@ -27,6 +27,11 @@ Invariants (pinned by `tests/test_asr.py`):
   framing, so feeding raw hop-aligned chunks is bit-identical to
   host-framed windows — the property the serving layer
   (`serve/stream.py`) relies on for requeue/replay.
+* **Frame rule.** The Hann windows the first ``fft_size`` samples of a
+  frame, so the graph takes ``window >= fft_size``
+  (`graph.py:frame_width`, checked by every entry and by the serving
+  layer); Kaldi's frame, shorter than the FFT and zero-padded to it, is
+  the ``"fbank"`` graph (`fbank.py`).
 * ``log1p`` (not ``log``) keeps the reference comparison well-posed for
   near-zero mel bins, mirroring `core.biosignal.band_power_features`.
 
@@ -50,7 +55,7 @@ from repro.kernels.pipeline.graph import (OutputSpec, build_graph,
                                           register_graph_factory,
                                           stream_frame_count)
 from repro.kernels.pipeline.kernel import (HIGHEST, _packed_rfft,
-                                           _table_operands)
+                                           fft_tables)
 from repro.kernels.pipeline.stages import register_stage
 
 __all__ = ["AsrFrontendApp", "make_asr_frontend", "mel_filterbank",
@@ -169,9 +174,10 @@ class AsrFrontendApp:
     """Streaming ASR feature front-end parameters (the graph's "app").
 
     Exposes ``fir_taps`` (pre-emphasis ``[1, -preemph]``; `core.fir`
-    convention ``y[t] = sum taps[i] * x[t-i]``) and ``fft_size`` so the
-    serving layer's app contract (`serve/stream.py` asserts
-    ``window >= app.fft_size``) holds unchanged."""
+    convention ``y[t] = sum taps[i] * x[t-i]``) and ``fft_size``. The
+    graph carries no ``frame_length``, so a stream's window follows the
+    graph's default frame rule (`graph.py:frame_width`: ``window >=
+    fft_size``, the Hann on the first fft_size samples)."""
     preemph: float = 0.97
     fft_size: int = 512
     n_mels: int = 64
@@ -204,16 +210,16 @@ def make_asr_frontend(**kw) -> AsrFrontendApp:
 
 
 def _asr_factory(app: AsrFrontendApp):
-    """Graph factory (`graph.py:register_graph_factory`): stage the app's
-    tables in the graph's operand binding order. Reuses the biosignal
-    twiddle/untangle staging (`kernel.py:_table_operands`) so both graphs
-    share one table-construction path."""
-    base, _ = _table_operands(app.fir_taps, np.zeros((1, 1), np.float32),
-                              np.zeros((1,), np.float32), app.fft_size)
-    taps, wr, wi, u = base[0], base[1], base[2], base[3]
-    operands = (taps, jnp.asarray(app.hann).reshape(1, app.fft_size),
-                wr, wi, u, jnp.asarray(app.mel_weights))
-    return asr_graph(2, app.fft_size, app.n_mels), operands
+    """Graph factory (`graph.py:register_graph_factory`): the app's host
+    tables in the graph's operand binding order, with the biosignal
+    graph's twiddle/untangle tables (`kernel.py:fft_tables`), so both
+    graphs share one table-construction path (the serving layer copies
+    them to a stream's device, `serve/stream.py:StagedTables`)."""
+    n = app.fft_size
+    operands = (np.asarray(app.fir_taps, np.float32).reshape(1, -1),
+                np.asarray(app.hann, np.float32).reshape(1, n),
+                *fft_tables(n), np.asarray(app.mel_weights, np.float32))
+    return asr_graph(2, n, app.n_mels), operands
 
 
 register_graph_factory("asr", _asr_factory, default_app=make_asr_frontend)

@@ -42,11 +42,20 @@ Invariants (pinned by `tests/test_stage_graph.py` / `tests/test_asr.py`):
 * **Stable names.** Each entry names its `pallas_call`
   ``graph_<mode>_kernel.<graph name>`` (`kernel_name`: modes ``frames``,
   ``stream``, ``ring``), whichever function jits it.
+* **A frame's width is the graph's rule** (`frame_width`). A graph
+  whose stages window the first ``fft_size`` samples of a frame takes
+  any ``window >= fft_size``. A graph whose params carry
+  ``frame_length`` (Kaldi's frame, zero-padded to the FFT) takes frames
+  of exactly that many samples: the kernel reads each frame
+  ``fft_size`` lanes wide, the frame count still follows
+  ``frame_length``, the signal is zero-padded at its tail, and the
+  graph's stages mask and window the lanes past ``frame_length`` to
+  zero.
 
 The biosignal graph is registered by `kernel.py` (name ``"biosignal"``),
-the ASR front-end by `asr.py` (name ``"asr"``); `get_graph_factory`
-resolves either by name for the serving layer
-(`serve/stream.py:StreamConfig.graph`).
+the ASR front-end by `asr.py` (name ``"asr"``), Kaldi's filterbank by
+`fbank.py` (name ``"fbank"``); `get_graph_factory` resolves each by name
+for the serving layer (`serve/stream.py:StreamConfig.graph`).
 """
 from __future__ import annotations
 
@@ -69,7 +78,7 @@ __all__ = ["OutputSpec", "StageGraph", "build_graph", "stages_to_run",
            "canonical_graph_outputs", "graph_empty_outputs",
            "register_graph_factory", "get_graph_factory", "default_app",
            "registered_graphs", "graph_pallas", "graph_stream_pallas",
-           "graph_ring_pallas", "stream_frame_count",
+           "graph_ring_pallas", "stream_frame_count", "frame_width",
            "min_stream_block_frames", "resolve_stream_block_frames",
            "ring_chunk_samples"]
 
@@ -176,9 +185,10 @@ class StageGraph:
     a STATIC argument of the jitted entries below — one compiled kernel
     per (graph, shape) like the legacy per-app entries. ``params`` must
     carry ``n_taps`` (the FIR-first framing contract) and ``fft_size``
-    (the rFFT segment length, also the minimum legal window). Build via
-    `build_graph`, which validates stage/operand wiring with the typed
-    `stages.py` errors."""
+    (the rFFT segment length), and may carry ``frame_length`` (frames of
+    that many samples, zero-padded to ``fft_size``; see `frame_width`).
+    Build via `build_graph`, which validates stage/operand wiring with
+    the typed `stages.py` errors."""
     name: str
     stages: tuple                    # Stage objects, dataflow order
     outputs: tuple                   # ((name, OutputSpec), ...)
@@ -235,6 +245,15 @@ def build_graph(name: str, stage_names, outputs, operands,
     for need in ("n_taps", "fft_size"):
         if need not in pdict:
             raise StageGraphError(f"graph {name!r}: missing param {need!r}")
+    if "frame_length" in pdict:
+        if not 0 < pdict["frame_length"] <= pdict["fft_size"]:
+            raise StageGraphError(
+                f"graph {name!r}: frame_length {pdict['frame_length']} must "
+                f"lie in (0, fft_size={pdict['fft_size']}]")
+        if any("window" in spec.shape for _, spec in outputs):
+            raise StageGraphError(
+                f"graph {name!r}: a zero-padded frame has no 'window'-wide "
+                f"output (its state is fft_size lanes wide)")
     bound = set(operands)
     read: set = set()
     produced: set = set()
@@ -266,6 +285,28 @@ def build_graph(name: str, stage_names, outputs, operands,
                 f"graph {name!r}: output {n!r} is produced by no stage")
     return StageGraph(name=name, stages=stages, outputs=outputs,
                       operands=operands, params=params)
+
+
+def frame_width(graph: StageGraph, window: int) -> int:
+    """Lanes one ``window``-sample frame takes inside the graph's kernel,
+    once the graph's own rule holds (else `StageGraphError`). A graph with
+    a ``frame_length`` param takes frames of exactly that length, read
+    ``fft_size`` lanes wide (the lanes past the frame are its zero
+    padding, which the graph's stages make zero); any other graph windows
+    the first ``fft_size`` samples of a frame, so ``window >= fft_size``
+    and the frame is read as it is."""
+    length = dict(graph.params).get("frame_length")
+    if length is not None:
+        if window != length:
+            raise StageGraphError(
+                f"graph {graph.name!r} frames {length} samples, got "
+                f"window={window}")
+        return graph.fft_size
+    if window < graph.fft_size:
+        raise StageGraphError(
+            f"graph {graph.name!r} needs window >= fft_size "
+            f"({window} < {graph.fft_size})")
+    return window
 
 
 def stages_to_run(graph: StageGraph, outputs: tuple) -> tuple:
@@ -339,6 +380,7 @@ def get_graph_factory(name: str) -> Callable:
     the typed `UnknownGraphError` on a miss."""
     if name not in _GRAPHS:
         import repro.kernels.pipeline.asr     # noqa: F401 (registers "asr")
+        import repro.kernels.pipeline.fbank   # noqa: F401 ("fbank")
         import repro.kernels.pipeline.kernel  # noqa: F401 ("biosignal")
     try:
         return _GRAPHS[name][0]
@@ -408,18 +450,19 @@ def graph_kernel(*refs, graph: StageGraph, outputs: tuple):
                 outputs)
 
 
-def graph_stream_kernel(*refs, graph: StageGraph, window: int, hop: int,
+def graph_stream_kernel(*refs, graph: StageGraph, width: int, hop: int,
                         block_frames: int, outputs: tuple, n_tails: int):
     """Raw-signal graph body with IN-KERNEL framing. The signal arrives as
     rows of ``hop`` samples: the body block holds this step's
-    `block_frames` rows, and (when window > hop) the tail block is the
-    next step's rows, which supply the window - hop overlap spill. The
+    `block_frames` rows, and (when width > hop) the tail block is the
+    next step's rows, which supply the width - hop overlap spill. The
     rows are laid end to end into one (1, span) chunk (whole rows only,
     so no slice starts off the (8, 128) tile), the graph's FIR runs once
-    over the chunk, frames are cut by static hop slices, and the first
-    ``n_taps - 1`` columns are patched with frame-local zero history, so
-    the result is bit-identical to running the graph on host-framed
-    windows. Shared verbatim by the (slot, block) ring grid."""
+    over the chunk, frames ``width`` lanes wide (`frame_width`) are cut by
+    static hop slices, and the first ``n_taps - 1`` columns are patched
+    with frame-local zero history, so the result is bit-identical to
+    running the graph on host-framed windows. Shared verbatim by the
+    (slot, block) ring grid."""
     n_taps, rb = graph.n_taps, block_frames
     rows = refs[0][...]
     if n_tails:
@@ -428,21 +471,23 @@ def graph_stream_kernel(*refs, graph: StageGraph, window: int, hop: int,
     tables = dict(zip(graph.operands, refs[i: i + len(graph.operands)]))
     out_refs = dict(zip(outputs, refs[i + len(graph.operands):]))
     taps_ref = tables[graph.stages[0].operands[0]]
-    span = (rb - 1) * hop + window
+    span = (rb - 1) * hop + width
     chunk = jnp.concatenate(
         [rows[r: r + 1] for r in range(-(-span // hop))], axis=1
     )[:, :span].astype(jnp.float32)
     # FIR once over the chunk (overlap shared in VMEM)
     filt_chunk = _fir_stage(chunk, taps_ref, n_taps)
-    filt = jnp.concatenate([filt_chunk[:, r * hop: r * hop + window]
+    filt = jnp.concatenate([filt_chunk[:, r * hop: r * hop + width]
                             for r in range(rb)], axis=0)
     # frame-local FIR transient: the framed reference zero-pads each
     # frame's history, the chunk FIR used real preceding samples — patch
-    # the first n_taps-1 columns (the only ones that can differ)
-    head = jnp.concatenate([chunk[:, r * hop: r * hop + n_taps - 1]
-                            for r in range(rb)], axis=0)
-    filt = jnp.concatenate([_fir_stage(head, taps_ref, n_taps),
-                            filt[:, n_taps - 1:]], axis=1)
+    # the first n_taps-1 columns (the only ones that can differ; a 1-tap
+    # FIR has none)
+    if n_taps > 1:
+        head = jnp.concatenate([chunk[:, r * hop: r * hop + n_taps - 1]
+                                for r in range(rb)], axis=0)
+        filt = jnp.concatenate([_fir_stage(head, taps_ref, n_taps),
+                                filt[:, n_taps - 1:]], axis=1)
     _write_graph_outputs(graph, out_refs,
                          _run_graph(graph, filt, tables, outputs))
 
@@ -500,16 +545,18 @@ def graph_frames_call(frames, operands, *, graph: StageGraph,
     graph)."""
     outputs = canonical_graph_outputs(graph, outputs)
     R, S = frames.shape
-    assert S >= graph.fft_size, (S, graph.fft_size)
+    width = frame_width(graph, S)
+    if width > S:                       # the frame's zero padding
+        frames = jnp.pad(frames, ((0, 0), (0, width - S)))
     # raw + filtered + two FFT planes ~= 4 live VWR blocks
-    rb = resolve_block_rows(R, S * 4, spec=VWRSpec(n_vwrs=4),
+    rb = resolve_block_rows(R, width * 4, spec=VWRSpec(n_vwrs=4),
                             override=block_rows)
     out_shape, out_specs = _graph_out_shapes_specs(graph, R, rb, S,
                                                    frames.dtype, outputs)
     outs = pl.pallas_call(
         functools.partial(graph_kernel, graph=graph, outputs=outputs),
         out_shape=out_shape,
-        in_specs=[pl.BlockSpec((rb, S), lambda i: (i, 0),
+        in_specs=[pl.BlockSpec((rb, width), lambda i: (i, 0),
                                memory_space=pltpu.VMEM)]
         + _operand_specs(operands),
         out_specs=out_specs,
@@ -553,15 +600,18 @@ def graph_stream_call(signal, operands, *, graph: StageGraph, window: int,
     """Unjitted raw-signal streaming core (jit wrapper:
     `graph_stream_pallas`). Exactly ONE `pallas_call` per call; the signal
     is zero-padded (or cut) to whole blocks of hop-sized rows and the
-    garbage tail frames are trimmed after the call."""
+    garbage tail frames are trimmed after the call. The frame count
+    follows ``window``; the kernel reads each frame `frame_width` lanes
+    wide, so a frame shorter than the FFT reads past its end, into the
+    next samples or the tail's zeros."""
     outputs = canonical_graph_outputs(graph, outputs)
     (S,) = signal.shape
-    assert window >= graph.fft_size, (window, graph.fft_size)
+    width = frame_width(graph, window)
     assert 0 < hop <= window, (hop, window)
     n = stream_frame_count(S, window, hop)
     if n == 0:
         return graph_empty_outputs(graph, window, signal.dtype, outputs)
-    rb, n_blocks, n_tails, rows = _stream_layout(n, window, hop, block_frames)
+    rb, n_blocks, n_tails, rows = _stream_layout(n, width, hop, block_frames)
     total = rows * hop
     sig = signal[:min(S, total)]
     if total > sig.shape[0]:
@@ -570,7 +620,7 @@ def graph_stream_call(signal, operands, *, graph: StageGraph, window: int,
     out_shape, out_specs = _graph_out_shapes_specs(
         graph, n_blocks * rb, rb, window, signal.dtype, outputs)
     outs = pl.pallas_call(
-        functools.partial(graph_stream_kernel, graph=graph, window=window,
+        functools.partial(graph_stream_kernel, graph=graph, width=width,
                           hop=hop, block_frames=rb, outputs=outputs,
                           n_tails=n_tails),
         out_shape=out_shape,
@@ -595,11 +645,11 @@ def graph_ring_call(ring, operands, *, graph: StageGraph, window: int,
     dispatch contract."""
     outputs = canonical_graph_outputs(graph, outputs)
     D, span = ring.shape
-    assert window >= graph.fft_size, (window, graph.fft_size)
+    width = frame_width(graph, window)
     assert 0 < hop <= window, (hop, window)
     n = stream_frame_count(span, window, hop)      # frames per ring slot
     assert n > 0, f"ring span {span} shorter than one {window}-window"
-    rb, n_blocks, n_tails, rows = _stream_layout(n, window, hop, block_frames)
+    rb, n_blocks, n_tails, rows = _stream_layout(n, width, hop, block_frames)
     total = rows * hop
     # pad every slot row to the block tiling (the pad frames are trimmed
     # per slot)
@@ -612,7 +662,7 @@ def graph_ring_call(ring, operands, *, graph: StageGraph, window: int,
         graph, D * n_blocks * rb, rb, window, ring.dtype, outputs,
         index_map=lambda r, j: (r * n_blocks + j, 0))
     outs = pl.pallas_call(
-        functools.partial(graph_stream_kernel, graph=graph, window=window,
+        functools.partial(graph_stream_kernel, graph=graph, width=width,
                           hop=hop, block_frames=rb, outputs=outputs,
                           n_tails=n_tails),
         out_shape=out_shape,

@@ -180,6 +180,14 @@ def pipeline_kernel(x_ref, taps_ref, wr_ref, wi_ref, u_ref, w_ref, b_ref,
     _write_outputs(refs, filt, feats, margin, cls)
 
 
+def fft_tables(fft_size: int) -> tuple:
+    """The packed rFFT's host tables: (stages, m) twiddles, real and
+    imaginary, and the (2, m) untangle factors, m = fft_size / 2."""
+    m = fft_size // 2
+    assert 1 << int(np.log2(m)) == m, f"fft_size={fft_size} not a power of 2"
+    return (*twiddle_table(m), untangle_table(fft_size))
+
+
 def _table_operands(taps, w, b, fft_size: int):
     """The staged constant tables every pipeline kernel reads: FIR taps,
     FFT twiddles, untangle factors and SVM weights/bias — with their
@@ -188,11 +196,9 @@ def _table_operands(taps, w, b, fft_size: int):
     F, C = w.shape
     m = fft_size // 2
     stages = int(np.log2(m))
-    assert 1 << stages == m, f"fft_size={fft_size} not a power of 2"
-    wr, wi = twiddle_table(m)
+    wr, wi, u = fft_tables(fft_size)
     operands = (jnp.asarray(taps, jnp.float32).reshape(1, k),
-                jnp.asarray(wr), jnp.asarray(wi),
-                jnp.asarray(untangle_table(fft_size)),
+                jnp.asarray(wr), jnp.asarray(wi), jnp.asarray(u),
                 jnp.asarray(w, jnp.float32),
                 jnp.asarray(b, jnp.float32).reshape(1, C))
     shapes = ((1, k), (stages, m), (stages, m), (2, m), (F, C),
@@ -298,10 +304,16 @@ def _biosignal_graph_operands(taps, w, b, fft_size: int):
 
 
 def _biosignal_factory(app):
-    """Graph factory (`graph.py:register_graph_factory`): bind a
-    `core.biosignal.BiosignalApp`'s taps/weights to the graph operands."""
-    return _biosignal_graph_operands(app.fir_taps, app.svm_w, app.svm_b,
-                                     app.fft_size)
+    """Graph factory (`graph.py:register_graph_factory`): a
+    `core.biosignal.BiosignalApp`'s taps/weights as the graph's host
+    tables, in `_table_operands` order (the serving layer copies them to
+    a stream's device, `serve/stream.py:StagedTables`)."""
+    w = np.asarray(app.svm_w, np.float32)
+    F, C = w.shape
+    taps = np.asarray(app.fir_taps, np.float32)
+    return (biosignal_graph(taps.shape[0], F, C, app.fft_size),
+            (taps.reshape(1, -1), *fft_tables(app.fft_size), w,
+             np.asarray(app.svm_b, np.float32).reshape(1, C)))
 
 
 register_graph_factory("biosignal", _biosignal_factory,

@@ -638,6 +638,11 @@ class ColumnScheduler:
         self.rebalance_ratio = rebalance_ratio
         self.rebalance_every = rebalance_every
         self.pending_moves: dict = {}
+        # the device copies of graph tables its streams share (one per
+        # graph, app and column; `serve/stream.py:StagedTables`)
+        from repro.serve.stream import StagedTables
+
+        self.tables = StagedTables()
         self._retired_since_rebalance = 0
         self._load = [0] * len(self.devices)
         self._placement: dict = {}
@@ -968,8 +973,9 @@ class ColumnScheduler:
 
     def place_stream(self, app=None, cfg=None, *, stream_id):
         """Admit + construct in one call: a `BiosignalStream` whose every
-        dispatch is committed to the assigned column and (when the
-        scheduler carries telemetry) reports its retires to it. (The
+        dispatch is committed to the assigned column, reading the graph
+        tables the scheduler keeps on that column (`tables`), and (when
+        the scheduler carries telemetry) reports its retires to it. (The
         unified admission path — `serve/frontend.py:ServeFrontend.submit`
         with a `StreamOpen` — lands here.)"""
         from repro.serve.stream import BiosignalStream
@@ -978,7 +984,8 @@ class ColumnScheduler:
         return BiosignalStream(app, cfg, device=device,
                                telemetry=self.telemetry,
                                stream_id=stream_id,
-                               column=self._placement[stream_id])
+                               column=self._placement[stream_id],
+                               tables=self.tables)
 
     def open_stream(self, app=None, cfg=None, *, stream_id):
         """Deprecated name for `place_stream` (kept as a shim for one

@@ -289,19 +289,22 @@ class ServeFrontend:
         """Pump + serve until every LM ticket resolves (stream tickets
         resolve at dispatch). Alternates admission pumps with
         `Engine.run_to_completion` so backpressured tickets re-enter as
-        the engine frees queue space. Returns all tickets ever issued."""
+        the engine frees queue space. Returns all tickets ever issued:
+        the front end's own list, not a copy, so a call costs nothing
+        per ticket issued before it."""
         while True:
             n = self.pump()
             inflight = bool(self._by_rid)
             if self.engine is not None and inflight:
                 done = self.engine.run_to_completion(max_steps=max_steps)
                 self._resolve_engine(done)
-            queued = any(t.status == "queued" for t in self.tickets)
-            if not queued and not self._by_rid:
+            # a ticket is queued exactly while it waits in _pending: no
+            # scan of every ticket ever issued, which grows with uptime
+            if not self._pending and not self._by_rid:
                 break
             if n == 0 and not inflight:
                 break   # wedged: nothing dispatched, nothing in flight
-        return list(self.tickets)
+        return self.tickets
 
     # ----------------------------------------------------- re-provisioning
 

@@ -52,13 +52,13 @@ from jax import lax
 from repro.kernels import interpret_mode
 from repro.core.biosignal import BiosignalApp, make_app
 from repro.kernels.pipeline.graph import (canonical_graph_outputs,
-                                          get_graph_factory,
+                                          frame_width,
                                           graph_empty_outputs,
                                           graph_ring_pallas,
                                           ring_chunk_samples)
 from repro.kernels.pipeline.ops import (OUTPUTS, canonical_outputs,
                                         default_app, stream_frame_count)
-from repro.serve.stream import StreamConfig, StreamTelemetry
+from repro.serve.stream import StagedTables, StreamConfig, StreamTelemetry
 
 DEFAULT_RING_DEPTH = 4
 
@@ -170,28 +170,32 @@ class ResidentStream:
                  rcfg: ResidentConfig | None = None, *, device=None,
                  telemetry: StreamTelemetry | None = None,
                  stream_id=None, column: int = 0,
-                 injector=None, retry=None):
+                 injector=None, retry=None,
+                 tables: StagedTables | None = None):
         cfg = cfg or StreamConfig()
         if cfg.graph == "biosignal":
             self.app = app or make_app()
+        else:
+            self.app = app if app is not None else default_app(cfg.graph)
+        # the loop is graph-generic: resolve (graph, operands on the
+        # stream's device) once here
+        self._graph, self._operands = (
+            tables if tables is not None else StagedTables()).get(
+                cfg.graph, self.app, device)
+        if cfg.graph == "biosignal":
             cfg = dataclasses.replace(
                 cfg, outputs=canonical_outputs(cfg.outputs))
         else:
-            self.app = app if app is not None else default_app(cfg.graph)
-            graph, _ = get_graph_factory(cfg.graph)(self.app)
             sel = None if cfg.outputs is OUTPUTS else cfg.outputs
             cfg = dataclasses.replace(
-                cfg, outputs=canonical_graph_outputs(graph, sel))
-        # the loop is graph-generic: resolve (graph, operands) once here
-        self._graph, self._operands = \
-            get_graph_factory(cfg.graph)(self.app)
+                cfg, outputs=canonical_graph_outputs(self._graph, sel))
         self.cfg = cfg
         self.rcfg = rcfg or ResidentConfig()
         assert self.cfg.framing == "kernel", \
             "the resident loop is a raw-chunk (framing='kernel') path"
         assert self.cfg.n_columns == 1 and self.cfg.column_weights is None, \
             "resident streams are column-pinned; use ColumnScheduler for D"
-        assert self.cfg.window >= self.app.fft_size
+        frame_width(self._graph, self.cfg.window)   # the graph's own rule
         assert 0 < self.cfg.hop <= self.cfg.window
         assert self.cfg.batch_windows > 0
         assert self.rcfg.ring_depth is None or self.rcfg.ring_depth >= 1

@@ -76,12 +76,12 @@ from jax import lax
 from repro.core.biosignal import BiosignalApp, make_app
 from repro.kernels import interpret_mode
 from repro.kernels.pipeline.graph import (canonical_graph_outputs,
-                                          get_graph_factory,
+                                          frame_width, get_graph_factory,
                                           graph_empty_outputs,
                                           graph_stream_call,
                                           graph_stream_pallas,
                                           ring_chunk_samples)
-from repro.kernels.pipeline.kernel import empty_outputs
+from repro.kernels.pipeline.kernel import biosignal_graph, empty_outputs
 from repro.kernels.pipeline.ops import (OUTPUTS, app_pipeline,
                                         app_pipeline_stream,
                                         canonical_outputs, default_app,
@@ -98,11 +98,14 @@ class StreamConfig:
     `serve.resident.ResidentStream`; the resident loop's own knobs live
     in `serve.resident.ResidentConfig`).
 
-    Invariants the runtimes assert: ``window >= app.fft_size`` (stage 4
-    reads the first fft_size samples of each frame), ``0 < hop <=
-    window`` (frames advance by whole hops; every chunk/deal boundary in
-    the kernel and the multi-column split is HOP-ALIGNED, which is what
-    makes raw-chunk feeds bit-identical to host framing), and
+    Invariants the runtimes check: ``window`` meets the graph's own
+    frame rule (`kernels.pipeline.graph.frame_width`: ``window >=
+    app.fft_size`` for graphs that window a frame's first fft_size
+    samples, ``window == frame_length`` for a frame zero-padded to the
+    FFT), ``0 < hop <= window`` (frames advance by whole hops; every
+    chunk/deal boundary in the kernel and the multi-column split is
+    HOP-ALIGNED, which is what makes raw-chunk feeds bit-identical to
+    host framing), and
     ``column_weights`` — when set — has exactly ``n_columns`` entries and
     requires ``framing="kernel"``. See `docs/ARCHITECTURE.md` (paper →
     code map) for how these knobs correspond to VWR2A's column/VWR
@@ -196,6 +199,43 @@ def _upload_loop(sig, operands, *, graph, window: int, hop: int,
     _, outs = lax.scan(dispatch, None, jnp.arange(n_batches))
     return {k: v.reshape((n_batches * batch_windows,) + v.shape[2:])[:n]
             for k, v in outs.items()}
+
+
+# the `_upload_loop` programs this process has run, by what jit keys them
+# on: a `serve.launch` span's ``compiled`` stat is 1 for a key's first run
+_UPLOAD_PROGRAMS: set = set()
+
+
+class StagedTables:
+    """Device copies of stage-graph operand tables, shared by the streams
+    of one serving layer: the graph factories build host tables
+    (`kernels/pipeline/graph.py:register_graph_factory`), and this keeps
+    ONE copy per (graph, app, device) for as long as the serving layer
+    lives, so admitting a stream copies nothing once its app's tables are
+    on its device, and a stream pinned to a device reads tables there.
+
+    Keyed by the app's identity (apps with ndarray fields aren't
+    hashable); an entry holds its app, so the identity stays its own. At
+    most ``CAPACITY`` entries are kept, the least recently used dropped
+    first (streams opened without an app each build their own)."""
+    CAPACITY = 16
+
+    def __init__(self):
+        self._entries: dict = {}      # key -> (app, graph, operands)
+
+    def get(self, graph_name: str, app, device=None) -> tuple:
+        """(StageGraph, operand tables on ``device``) of ``app`` under the
+        registered graph ``graph_name`` (``device`` None: the default
+        placement)."""
+        key = (graph_name, id(app), device)
+        hit = self._entries.pop(key, None)
+        if hit is None:
+            graph, tables = get_graph_factory(graph_name)(app)
+            hit = (app, graph, tuple(jax.device_put(list(tables), device)))
+            if len(self._entries) >= self.CAPACITY:
+                del self._entries[next(iter(self._entries))]
+        self._entries[key] = hit            # most recently used last
+        return hit[1], hit[2]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -368,16 +408,23 @@ class BiosignalStream:
                  cfg: StreamConfig | None = None, *, device=None,
                  telemetry: StreamTelemetry | None = None,
                  stream_id=None, column: int = 0,
-                 injector=None, retry=None):
+                 injector=None, retry=None,
+                 tables: StagedTables | None = None):
         cfg = cfg or StreamConfig()
+        self._tables = tables if tables is not None else StagedTables()
         if cfg.graph == "biosignal":
             self.app = app or make_app()
             self._graph = None          # biosignal keeps its sharded path
+            # its tables are staged on the first upload (`_staged`), after
+            # the signal's copy is under way; the rule needs the graph only
+            rule = biosignal_graph(len(self.app.fir_taps),
+                                   *self.app.svm_w.shape, self.app.fft_size)
             cfg = dataclasses.replace(
                 cfg, outputs=canonical_outputs(cfg.outputs))
         else:
             self.app = app if app is not None else default_app(cfg.graph)
-            self._graph, _ = get_graph_factory(cfg.graph)(self.app)
+            self._graph, _ = self._tables.get(cfg.graph, self.app, device)
+            rule = self._graph
             # the config default (the biosignal 4-tuple) means "all of
             # THIS graph's outputs" for a non-biosignal graph
             sel = None if cfg.outputs is OUTPUTS else cfg.outputs
@@ -386,8 +433,7 @@ class BiosignalStream:
             assert cfg.n_columns == 1 and cfg.column_weights is None, \
                 "non-biosignal graphs are single-column (no sharded entry)"
         self.cfg = cfg
-        assert self.cfg.window >= self.app.fft_size, (
-            self.cfg.window, self.app.fft_size)
+        frame_width(rule, cfg.window)           # the graph's own frame rule
         assert 0 < self.cfg.hop <= self.cfg.window
         assert self.cfg.batch_windows > 0
         assert self.cfg.framing in ("kernel", "host"), self.cfg.framing
@@ -575,10 +621,12 @@ class BiosignalStream:
         return empty_outputs(self.cfg.window, w[0], w[1], dtype,
                              self.cfg.outputs)
 
-    @functools.cached_property
+    @property
     def _staged(self) -> tuple:
-        """(StageGraph, operand tables) the per-upload loop runs."""
-        return get_graph_factory(self.cfg.graph)(self.app)
+        """(StageGraph, operand tables on the stream's device) the
+        per-upload loop runs, from the serving layer's `StagedTables`
+        (copied there by the first stream of this app and device)."""
+        return self._tables.get(self.cfg.graph, self.app, self.device)
 
     def _loop_block_frames(self, dtype) -> int | None:
         """The frame-block a per-batch dispatch would use: the pinned
@@ -607,11 +655,14 @@ class BiosignalStream:
         injector fires once per dispatch, in order and each through the
         retry, before the program runs; the telemetry sees one retire of
         all ``n`` frames. Spans: ``serve.prepare`` (the signal's copy to
-        the stream's device), one ``serve.launch`` (``valid`` frames of
-        ``slots``, over ``batches`` dispatches), ``serve.retire`` around
+        the stream's device, ``samples`` long), one ``serve.launch``
+        (``valid`` frames of ``slots``, over ``batches`` dispatches, of the
+        registered ``graph``; ``compiled`` 1 on the process's first
+        upload of this length and loop shape, the one that compiles its
+        loop program), ``serve.retire`` around
         ``serve.wait``."""
         cfg = self.cfg
-        with span("prepare", dispatch=0):
+        with span("prepare", dispatch=0, samples=len(signal)):
             sig = jax.device_put(signal, self.device)
         assert sig.ndim == 1, sig.shape
         n = frame_count(sig.shape[0], cfg.window, cfg.hop)
@@ -620,15 +671,20 @@ class BiosignalStream:
         batches = -(-n // cfg.batch_windows)
         graph, operands = self._staged
         with span("launch", dispatch=0, valid=n,
-                  slots=batches * cfg.batch_windows, batches=batches):
+                  slots=batches * cfg.batch_windows, batches=batches,
+                  graph=graph.name) as launch:
             if self.injector is not None:
                 for _ in range(batches):
                     self._retry.call(self.injector.on_dispatch, self.column)
-            out = _upload_loop(
-                sig, operands, graph=graph, window=cfg.window, hop=cfg.hop,
-                batch_windows=cfg.batch_windows, interpret=interpret_mode(),
-                block_frames=self._loop_block_frames(sig.dtype),
-                outputs=cfg.outputs)
+            static = dict(graph=graph, window=cfg.window, hop=cfg.hop,
+                          batch_windows=cfg.batch_windows,
+                          interpret=interpret_mode(),
+                          block_frames=self._loop_block_frames(sig.dtype),
+                          outputs=cfg.outputs)
+            key = (sig.shape, sig.dtype, *static.values())
+            launch.set_metadata(compiled=int(key not in _UPLOAD_PROGRAMS))
+            _UPLOAD_PROGRAMS.add(key)
+            out = _upload_loop(sig, operands, **static)
         with span("retire", dispatch=0, valid=n):
             with span("wait", dispatch=0):
                 out = jax.block_until_ready(out)
@@ -671,5 +727,5 @@ class BiosignalStream:
                 self.app, self.cfg, rcfg, device=self.device,
                 telemetry=self.telemetry, stream_id=self.stream_id,
                 column=self.column, injector=self.injector,
-                retry=self._retry)
+                retry=self._retry, tables=self._tables)
         return self._resident.process(signal)

@@ -66,6 +66,24 @@ def test_stream_ticket_resolves_at_dispatch(setup):
     assert t.result().column == sched.column_of("s-1")
 
 
+def test_run_reads_no_ticket_resolved_before_it():
+    """Admission costs nothing per ticket already resolved (a server's
+    tickets grow with its uptime): `run` reads none of them, and returns
+    the front end's own list."""
+    class Resolved:
+        def __getattr__(self, name):
+            raise AssertionError(f"run read {name!r} of a resolved ticket")
+
+    front = ServeFrontend(scheduler=ColumnScheduler(devices=["c0"]))
+    for i in range(3):
+        front.submit(StreamOpen(stream_id=f"s-{i}"))
+    front.run()
+    front.tickets[:] = [Resolved() for _ in front.tickets]
+    t = front.submit(StreamOpen(stream_id="s-3"))
+    assert front.run() is front.tickets
+    assert t.status == "done" and front.tickets[-1] is t
+
+
 def test_both_classes_one_front_end(setup):
     """The headline: LM requests and stream opens through ONE verb, one
     queue, both resolving with class-appropriate results."""

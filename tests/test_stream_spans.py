@@ -7,8 +7,9 @@ with `jax.profiler.ProfileData`: one ``serve.process`` per call; in the
 per-batch loop one ``serve.launch`` per dispatch carrying its real frames
 (``valid``), its slots and ``batches=1``, ``prepare``/``launch``/
 ``retire`` tied by ``dispatch``, each ``serve.wait`` inside its
-``serve.retire``; in the per-upload loop one of each, the launch carrying
-every batch of the upload; and an admission span that ties a ticket to
+``serve.retire``; in the per-upload loop one of each, the prepare carrying
+the upload's samples and the launch every batch of the upload, its graph
+and whether it compiled a loop program; and an admission span that ties a ticket to
 the stream it opened. The outputs do not depend on a
 session being active.
 """
@@ -102,9 +103,11 @@ def test_process_records_one_span_tree(app, signal, framing, tmp_path):
     (launch,) = launches
     (retire,) = _named(ev, "retire")
     (wait,) = _named(ev, "wait")
+    # compiled outside the trace, so this launch built no loop program
     assert launch[3] == {"dispatch": 0, "valid": N_FRAMES, "slots": k * BW,
-                         "batches": k}
-    assert prepare[3] == {"dispatch": 0} and wait[3] == {"dispatch": 0}
+                         "batches": k, "graph": "biosignal", "compiled": 0}
+    assert prepare[3] == {"dispatch": 0, "samples": signal.shape[0]}
+    assert wait[3] == {"dispatch": 0}
     assert retire[3] == {"dispatch": 0, "valid": N_FRAMES}
     assert prepare[2] <= launch[1] and launch[2] <= retire[1]
     assert _inside(wait, retire)

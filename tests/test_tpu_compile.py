@@ -4,8 +4,10 @@ The TPU compiler is installed even where no chip is attached, so each test
 lowers a kernel with ``interpret=False`` against a described ``v5e:2x2``
 topology and compiles it for one of its chips at the real widths: the
 biosignal graph at 2048 / 512 (also inside the stream's per-upload loop
-at the archive length), the ASR graph at 512 / 128, and the standalone
-FIR, FFT and RoPE kernels. A compile that passes says the
+at the archive length), the ASR graph at 512 / 128, Kaldi's fbank graph
+at 400 / 160 (its 400-sample frame read 512 lanes wide, also inside the
+per-upload loop at the longest utterance), and the standalone FIR, FFT
+and RoPE kernels. A compile that passes says the
 kernel is Mosaic-legal (tiling, VMEM, lowering rules); it runs nothing.
 Each compiled program must hold the Mosaic kernel (``tpu_custom_call``).
 
@@ -29,7 +31,7 @@ from repro.kernels.pipeline.graph import (default_app, get_graph_factory,
 from repro.kernels.rope.kernel import rope_pallas
 from repro.serve.stream import _upload_loop
 
-GRAPHS = {"biosignal": (2048, 512), "asr": (512, 128)}
+GRAPHS = {"biosignal": (2048, 512), "asr": (512, 128), "fbank": (400, 160)}
 
 
 @pytest.fixture(scope="module")
@@ -90,29 +92,40 @@ def test_biosignal_ring_compiles(one_chip):
         _shape((4, span), one_chip), *ops)
 
 
-def test_biosignal_upload_loop_compiles(one_chip):
-    """`BiosignalStream.process`'s per-upload program at the archive
-    length (8 h at 64 Hz): the stream kernel runs inside the loop's body,
-    under the name the device trace gives its launches."""
-    window, hop = GRAPHS["biosignal"]
-    graph, ops = _graph("biosignal", one_chip)
+def _assert_upload_loop(name, n_samples, outputs, one_chip):
+    """`BiosignalStream.process`'s per-upload program for graph ``name``:
+    the stream kernel runs inside the loop's body, under the name the
+    device trace gives its launches."""
+    window, hop = GRAPHS[name]
+    graph, ops = _graph(name, one_chip)
     text = jax.jit(lambda x, *o: _upload_loop(
         x, o, graph=graph, window=window, hop=hop, batch_windows=64,
         interpret=False, block_frames=None,
-        outputs=("features", "margin", "class"))).lower(
-        _shape((1_843_200,), one_chip), *ops).compile().as_text()
+        outputs=outputs)).lower(
+        _shape((n_samples,), one_chip), *ops).compile().as_text()
     comps = {m.group(1): m.group(0) for m in re.finditer(
         r"^(?:ENTRY )?%([\w.\-]+) \(.*?^}", text, re.M | re.S)}
     (body,) = re.findall(r"body=%([\w.\-]+)", text)
     reached, todo = set(), [body]
     while todo:
-        name = todo.pop()
-        if name not in reached:
-            reached.add(name)
-            todo += re.findall(r"calls=%([\w.\-]+)", comps[name])
+        comp = todo.pop()
+        if comp not in reached:
+            reached.add(comp)
+            todo += re.findall(r"calls=%([\w.\-]+)", comps[comp])
     assert any("tpu_custom_call" in comps[c] for c in reached)
-    assert re.search(r"^\s*%graph_stream_kernel\.biosignal[.\d]* = ",
+    assert re.search(rf"^\s*%graph_stream_kernel\.{name}[.\d]* = ",
                      comps[body], re.M)
+
+
+def test_biosignal_upload_loop_compiles(one_chip):
+    """At the archive length (8 h at 64 Hz)."""
+    _assert_upload_loop("biosignal", 1_843_200,
+                        ("features", "margin", "class"), one_chip)
+
+
+def test_fbank_upload_loop_compiles(one_chip):
+    """At the longest utterance of the backlog (27.1 s at 16 kHz)."""
+    _assert_upload_loop("fbank", 433_600, ("fbank",), one_chip)
 
 
 def test_fir_kernel_compiles(one_chip):
