@@ -17,16 +17,13 @@ Everything is jit-able; the windowed app is a pure function of the signal.
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import numpy as np
 import jax
 import jax.numpy as jnp
-import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.fft import rfft_packed
 from repro.core.fir import fir_direct, lowpass_taps
-from repro.core.shuffle import compact_even
 
 
 # ---------------------------------------------------------------------------
@@ -61,9 +58,7 @@ def delineate(x, *, min_prominence: float = 0.3, min_distance: int = 15):
     Returns (is_max, is_min): boolean masks over the window. This is the
     paper's 'lots of if conditions' step, recast as vector predicates. The
     refractory gate also bounds the interval density — consecutive
-    extrema sit >= min_distance + 1 apart (ties excepted), which keeps the
-    interval-median's fixed-size `INTERVAL_SLOTS` sorting network on its
-    fast path for windows up to INTERVAL_SLOTS*(min_distance+1) samples.
+    extrema sit >= min_distance + 1 apart (ties excepted).
     """
     prev = jnp.roll(x, 1, axis=-1)
     nxt = jnp.roll(x, -1, axis=-1)
@@ -104,42 +99,6 @@ def _masked_intervals_sort(mask):
     return mean, med, rms
 
 
-def network_sort(x):
-    """Ascending sort along the last (power-of-two) axis via Batcher's
-    odd-even merge network: O(log^2 n) vectorized stages of rotate +
-    compare + select. Stage (p = 2^a, k) compare-exchanges the disjoint
-    pairs (t, t + k): a "lo" slot keeps min(x[t], x[t+k]), a "hi" slot
-    keeps max(x[t], x[t-k]) — classic Batcher pairing, t in the upper-k
-    half of its 2k-group (offset by k % p), both endpoints in the same
-    2p-block. The masks are iota arithmetic, so the network needs no
-    table operand, and the traced shifts are `pltpu.roll` lane rotates
-    (a traced `jnp.roll` is a dynamic slice, which Mosaic cannot lower;
-    outside a kernel `pltpu.roll` lowers to `jnp.roll`): it runs under
-    Mosaic and XLA alike."""
-    n = x.shape[-1]
-    assert n & (n - 1) == 0, f"network_sort needs a power-of-two length: {n}"
-    ax = x.ndim - 1
-    t = jax.lax.broadcasted_iota(jnp.int32, x.shape, ax)
-
-    def stage(a, j, y):
-        k = jnp.left_shift(jnp.int32(1), a - j)
-        kmodp = jnp.where(j == 0, 0, k)      # k % p: k == p exactly at j == 0
-        blk = t >> (a + 1)
-        lo = (((t - kmodp) & (2 * k - 1)) < k) & (t + k < n) & \
-            (blk == ((t + k) >> (a + 1)))
-        u = t - k                            # hi[t] == lo[t - k]
-        hi = (u >= 0) & (((u - kmodp) & (2 * k - 1)) < k) & \
-            ((u >> (a + 1)) == blk)
-        fwd = pltpu.roll(y, n - k, ax)       # y[t + k]
-        bwd = pltpu.roll(y, k, ax)           # y[t - k]
-        return jnp.where(lo, jnp.minimum(y, fwd),
-                         jnp.where(hi, jnp.maximum(y, bwd), y))
-
-    for a in range(n.bit_length() - 1):      # p = 2^a; k = p, p/2, ..., 1
-        x = jax.lax.fori_loop(0, a + 1, functools.partial(stage, a), x)
-    return x
-
-
 def _interval_gaps(mask):
     """Gaps between consecutive True positions as mask algebra: a running
     max of the last-seen True index replaces the seed's compaction sort.
@@ -165,23 +124,18 @@ def _interval_gaps(mask):
     return gaps, valid
 
 
-def _masked_intervals(mask, *, sparse2: bool = False):
+def _masked_intervals(mask):
     """Mean/median/RMS of gaps between consecutive True positions (masked
     statistics, fixed shapes — jit-friendly).
 
     Mosaic-compilable formulation: gap extraction is running-max mask
-    algebra (`_interval_gaps`), the median is `network_sort` + a one-hot
-    k-th-order pick, and every fold is rotate + select. Matches
-    `_masked_intervals_sort` exactly — gap values are small integers, so
-    the f32 reductions are order-independent.
-
-    The median network runs on the fixed `INTERVAL_SLOTS` buffer: the gap
-    array (sentinel-padded) is folded by min over segments of G slots.
-    That is exact whenever no segment holds two gaps; any segment that
-    does routes the whole batch to a full-length network (rare, slower,
-    always exact). ``sparse2`` promises no two ADJACENT positions are both
-    True (always the case for `delineate` extrema: a strict rise cannot
-    follow itself), which halves the slots the fold has to cover."""
+    algebra (`_interval_gaps`), and the median is an exact counting
+    selection. Gaps are integers in [1, S), so the lower median (the k-th
+    smallest, k = (n - 1) // 2) is the least v with count(gaps <= v) > k:
+    a per-row bisection over [0, S] in ceil(log2 S) static steps, each one
+    compare and one lane sum — no sort, gather or branch. Matches
+    `_masked_intervals_sort` exactly: every count and every f32 reduction
+    of small integers is order-independent."""
     S = mask.shape[-1]
     gaps, valid = _interval_gaps(mask)
     nv = jnp.sum(valid, axis=-1)
@@ -189,51 +143,18 @@ def _masked_intervals(mask, *, sparse2: bool = False):
     g = jnp.where(valid, gaps, 0).astype(jnp.float32)
     mean = jnp.sum(g, axis=-1) / n
     rms = jnp.sqrt(jnp.sum(jnp.square(g), axis=-1) / n)
-    big = jnp.iinfo(jnp.int32).max
-    vals = jnp.where(valid, gaps, big)
-    k = ((n - 1) // 2)[..., None]
-    K = INTERVAL_SLOTS
-
-    def kth_smallest(svals):
-        sel = jax.lax.broadcasted_iota(jnp.int32, svals.shape,
-                                       svals.ndim - 1)
-        return jnp.sum(jnp.where(sel == k, svals, 0), axis=-1)
-
-    def pad(v, width):
-        if width == S:
-            return v
-        return jnp.concatenate(
-            [v, jnp.full(mask.shape[:-1] + (width - S,), big, v.dtype)],
-            axis=-1)
-
-    def pow2(m):
-        return 1 << max(m - 1, 0).bit_length()
-
-    pre = 2 if sparse2 and S % 2 == 0 else 1
-    width = pre * max(pow2(S // pre), K)
-    seg = width // K                       # slots folded into one
-    buf = pad(vals, width)
-    if seg == 1:
-        # no lossy fold: the fixed-size network is always exact
-        med = kth_smallest(network_sort(buf))
-    else:
-        # a segment holds two gaps iff its windowed count exceeds 1
-        cnt = (buf < big).astype(jnp.int32)
-        folded = buf
-        step = 1
-        while step < seg:
-            cnt = cnt + jnp.roll(cnt, -step, axis=-1)
-            folded = compact_even(jnp.minimum(folded,
-                                              jnp.roll(folded, -1, axis=-1)))
-            step *= 2
-        pos = jax.lax.broadcasted_iota(jnp.int32, cnt.shape, cnt.ndim - 1)
-        collide = jnp.any((cnt > 1) & ((pos & (seg - 1)) == 0))
-        folded = folded[..., :K]
-        full = pad(vals, pow2(S))
-        med = jax.lax.cond(collide,
-                           lambda: kth_smallest(network_sort(full)),
-                           lambda: kth_smallest(network_sort(folded)))
-    med = jnp.where(nv > 0, med, 0).astype(jnp.float32)
+    vals = jnp.where(valid, gaps, S + 1)       # invalid slots are never counted
+    k = ((n - 1) // 2)[..., None].astype(jnp.float32)
+    # invariant: count(vals <= lo) <= k < count(vals <= hi) once nv > 0
+    lo = jnp.zeros(k.shape, jnp.int32)
+    hi = jnp.full(k.shape, S, jnp.int32)
+    for _ in range(max(S - 1, 1).bit_length()):
+        mid = (lo + hi) >> 1
+        c = jnp.sum((vals <= mid).astype(jnp.float32), axis=-1, keepdims=True)
+        take = c > k
+        hi = jnp.where(take, mid, hi)
+        lo = jnp.where(take, lo, mid)
+    med = jnp.where(nv > 0, hi[..., 0], 0).astype(jnp.float32)
     return mean, med, rms
 
 
@@ -241,29 +162,19 @@ def _masked_intervals(mask, *, sparse2: bool = False):
 # Features + SVM
 # ---------------------------------------------------------------------------
 
-# The FIXED size of the interval median's sorting network: one VWR worth of
-# interval candidates (128 32-bit words, paper §3.1). Windows whose folded
-# gap array is longer are compacted into this buffer by segment folding
-# (exact whenever no segment holds two intervals — guarded, with a full-
-# length network fallback), so the kernel's hot sort always runs at 128
-# slots regardless of the window length.
-INTERVAL_SLOTS = 128
-
-
 def interval_time_features(is_max, is_min) -> list:
     """The 6 time features: mean/median/RMS of the inspiration and
     expiration interval lengths (single source — also run inside the fused
-    pipeline kernel). Both masks ride ONE sorting-network pass (stacked
-    along the batch axis), and extrema are never adjacent, so the median
-    fold covers half the window (`sparse2`)."""
+    pipeline kernel). Both masks ride ONE counting-selection pass
+    (stacked along the batch axis)."""
     if is_max.ndim >= 2:
         both = jnp.concatenate([is_max, is_min], axis=0)
-        mean, med, rms = _masked_intervals(both, sparse2=True)
+        mean, med, rms = _masked_intervals(both)
         R = is_max.shape[0]
         return [mean[:R], med[:R], rms[:R], mean[R:], med[R:], rms[R:]]
     f_time = []
     for mask in (is_max, is_min):
-        mean, med, rms = _masked_intervals(mask, sparse2=True)
+        mean, med, rms = _masked_intervals(mask)
         f_time += [mean, med, rms]
     return f_time
 

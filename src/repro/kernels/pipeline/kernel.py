@@ -26,10 +26,10 @@ kernel:
 Inter-stage tensors never leave the block: the working set is budgeted
 against `VWRSpec(n_vwrs=4)` (raw + filtered + FFT planes + table/epilogue
 scratch). Numerics follow `core.biosignal` op-for-op so the fused outputs
-match the staged app to f32 tolerance. The delineation/median stage runs a
-fixed-size odd-even sorting network with iota-arithmetic masks (no `sort`
-/ `take_along_axis` / gather anywhere in the kernel), and every stage
-compiles for the TPU v5e (`tests/test_tpu_compile.py`).
+match the staged app to f32 tolerance. The interval median is an exact
+counting selection — a bisection of compare + lane-sum steps (no `sort` /
+`take_along_axis` / gather / branch anywhere in the kernel), and every
+stage compiles for the TPU v5e (`tests/test_tpu_compile.py`).
 
 `pipeline_stream_pallas` is the RAW-SIGNAL entry: the grid iterates
 frame-blocks over a 1-D signal and the overlapping (window, hop) frames
@@ -254,7 +254,7 @@ def _delineate_body(state, tables, params):
                 requires=("filtered", "is_max", "is_min"),
                 produces=("features",))
 def _features_body(state, tables, params):
-    """Masked interval time features (odd-even network median) +
+    """Masked interval time features (counting-selection median) +
     packed-rFFT band powers, stacked to (rb, 12)."""
     f_time = interval_time_features(state["is_max"], state["is_min"])
     f_freq = _rfft_band_powers(
