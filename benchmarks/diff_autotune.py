@@ -20,6 +20,11 @@ threshold derived from the two runs' own rep spreads:
 * ratio-less or mixed records are reported informationally, never failed
   (gating raw us across different runner hardware would flap).
 
+A pinned record that the baseline has and the current run lacks fails the
+gate, unless its name is in ``RETIRED``: a shape whose code was deleted
+on purpose. Retired names are reported, never failed; every other missing
+record still fails.
+
 Raw winner drift (a different measured block choice) stays informational
 even under ``--gate`` — on shared runners near-tied candidates flip on
 machine noise; the gate fires only when the pinned perf actually moved.
@@ -47,6 +52,12 @@ SPREAD_MULT = 3.0
 # distinct exit path for "the baseline artifact never arrived": neither
 # the green 0 nor the regression 1
 EXIT_NO_BASELINE = 3
+# pinned records whose measured code was deleted: a baseline that still
+# holds them reports them as retired instead of failing the gate
+RETIRED = {
+    "table5/stream_resident": "the device-resident ring loop it timed "
+                              "was deleted",
+}
 
 
 def _read(path: str):
@@ -102,6 +113,9 @@ def gate_pinned(old: dict, new: dict) -> tuple[list[str], list[str]]:
     for name in sorted(new.keys() - old.keys()):
         report.append(f"{name}: new pinned shape (no previous record)")
     for name in sorted(old.keys() - new.keys()):
+        if name in RETIRED:
+            report.append(f"{name}: retired ({RETIRED[name]})")
+            continue
         failures.append(f"{name}: pinned record DISAPPEARED — the bench "
                         f"no longer measures this shape")
     return report, failures

@@ -69,16 +69,6 @@ def main() -> None:
     ap.add_argument("--hetero-ratio", type=float, default=1.15,
                     metavar="R", help="--check-hetero threshold "
                     "(default 1.15)")
-    ap.add_argument("--check-resident", action="store_true",
-                    help="fail unless the device-resident steady-state "
-                         "loop (*/stream_resident) is at least as fast as "
-                         "the host-driven per-batch dispatch loop "
-                         "(*/stream_perbatch) — the on-device control-flow "
-                         "gate (rows are timed paired)")
-    ap.add_argument("--resident-ratio", type=float, default=1.0,
-                    metavar="R", help="--check-resident threshold "
-                    "(default 1.0: resident must not lose to per-batch "
-                    "dispatch)")
     ap.add_argument("--check-fault", action="store_true",
                     help="fail unless killing one of D=4 columns mid-run "
                          "(*/stream_fault_recovered) keeps the modelled "
@@ -226,24 +216,6 @@ def main() -> None:
                 raise SystemExit(1)
             print(f"check-hetero ok: {dyn} {ud:.1f}us, {stat} {us:.1f}us "
                   f"({us / ud:.2f}x)")
-    if args.check_resident:
-        by_name = {r["name"]: r["us_per_call"] for r in rows}
-        pairs = [(n, n.rsplit("stream_resident", 1)[0] + "stream_perbatch")
-                 for n in by_name if n.endswith("stream_resident")]
-        if not pairs:
-            print("check-resident: no stream_resident rows found",
-                  file=sys.stderr)
-            raise SystemExit(1)
-        for res, host in pairs:
-            ur, uh = by_name[res], by_name.get(host)
-            if uh is None or uh < args.resident_ratio * ur:
-                print(f"check-resident FAILED: {res}={ur:.1f}us vs "
-                      f"{host}={uh}us (resident must be >= "
-                      f"{args.resident_ratio}x per-batch dispatch)",
-                      file=sys.stderr)
-                raise SystemExit(1)
-            print(f"check-resident ok: {res} {ur:.1f}us, {host} "
-                  f"{uh:.1f}us ({uh / ur:.2f}x)")
     if args.check_fault:
         by_name = {r["name"]: r for r in rows}
         pairs = [(n, n.rsplit("stream_fault_recovered", 1)[0] +

@@ -395,50 +395,6 @@ def _hetero_rows():
     ]
 
 
-def _resident_rows():
-    """Device-resident steady-state loop vs the host-driven per-batch
-    dispatch loop: the SAME signal, config, and fused kernel — the only
-    difference is where the loop runs. The per-batch path pays one
-    Python-loop round trip (dispatch + retire + telemetry) per
-    `batch_windows` frames; the resident path runs the whole steady state
-    as ONE compiled `lax.scan` over ring sweeps
-    (`serve/resident.py:ResidentStream`), bit-identical outputs. Timed
-    paired; the CI bench smoke gates resident >= per-batch dispatch
-    throughput via ``run.py --check-resident``."""
-    from repro.core.biosignal import make_app, synthetic_respiration
-    from repro.serve.resident import ResidentConfig, ResidentStream
-    from repro.serve.stream import BiosignalStream, StreamConfig
-
-    app = make_app()
-    window, hop, bw, ring = 2048, 512, 8, 4
-    cfg = StreamConfig(window=window, hop=hop, batch_windows=bw,
-                       outputs=("features", "margin", "class"))
-    sig, _ = synthetic_respiration(1, 512 * 120 + window, seed=5)
-    raw = sig[0]
-    n = (raw.shape[0] - window) // hop + 1
-    n_batches = -(-n // bw)
-    n_sweeps = -(-n_batches // ring)
-    host = BiosignalStream(app, cfg)
-    res = ResidentStream(app, cfg, ResidentConfig(ring_depth=ring))
-    t_res, t_host = _paired_times([lambda: res.process(raw),
-                                   lambda: host.process(raw)], reps=11)
-    us_res, us_host = min(t_res), min(t_host)
-    from repro.core import autotune
-
-    autotune.record_pinned("table5/stream_resident", t_res,
-                           baseline_us=t_host)
-    return [
-        ("table5/stream_perbatch", us_host,
-         f"host-driven dispatch loop, {n_batches} round trips of "
-         f"{bw} frames (window={window},hop={hop})"),
-        ("table5/stream_resident", us_res,
-         f"device-resident lax.scan loop, ring_depth={ring} "
-         f"({n_sweeps} sweeps, 1 host dispatch);"
-         f"windows_per_s={n / us_res * 1e6:.0f};"
-         f"speedup_vs_perbatch={us_host / us_res:.2f}x"),
-    ]
-
-
 def _depth_rows():
     """Streaming-runtime pipelining depth: depth=1 (the classic double
     buffer — consume batch k while k+1 is in flight) vs depth=2 (two
@@ -727,7 +683,6 @@ def run():
     rows += _asr_rows()
     rows += _column_rows()
     rows += _hetero_rows()
-    rows += _resident_rows()
     rows += _depth_rows()
     rows += _fault_rows()
     rows += _engine_fault_rows()

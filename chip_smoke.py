@@ -10,9 +10,8 @@ One chip, in this one process and through the normal entry points:
 2. biosignal stream: the MBioTracker config (2048-sample windows, hop 512,
    11-tap FIR, 512-point rFFT, SVM) admitted with
    `ServeFrontend.submit(StreamOpen(...))`; 2^23 seeded samples through
-   the per-upload loop (`BiosignalStream.process`), the per-batch loop
-   (`BiosignalStream.stream`) and the device-resident loop
-   (`ResidentStream.process`). The three must agree bit for bit, and
+   the per-upload loop (`BiosignalStream.process`) and the per-batch loop
+   (`BiosignalStream.stream`). The two must agree bit for bit, and
    match the staged jnp reference (`kernels/pipeline/ref.py`);
 3. ASR front-end: 10 minutes of seeded 16 kHz audio through the "asr"
    stage graph at 512 / 128, checked against `asr.py:asr_reference`;
@@ -109,7 +108,7 @@ def device_check(n_chips: int):
 
 
 # ---------------------------------------------------------------------------
-# Biosignal stream: per-batch and device-resident loops
+# Biosignal stream: per-upload and per-batch loops
 # ---------------------------------------------------------------------------
 
 def check_biosignal_reference(app, sig, out, window, hop, frames):
@@ -140,10 +139,8 @@ def biosignal_phase(front, seed: int) -> None:
     from repro.kernels.fft.kernel import fft_pallas
     from repro.kernels.fir.kernel import fir_pallas
     from repro.kernels.pipeline.kernel import (OUTPUTS,
-                                               pipeline_ring_pallas,
                                                pipeline_stream_pallas)
     from repro.serve.frontend import StreamOpen
-    from repro.serve.resident import ResidentConfig
     from repro.serve.stream import StreamConfig, frame_count
 
     window, hop = BIO.window_samples, 512
@@ -162,8 +159,6 @@ def biosignal_phase(front, seed: int) -> None:
     taps, w, b = app.fir_taps, app.svm_w, app.svm_b
     assert_mosaic("biosignal stream kernel", pipeline_stream_pallas,
                   chunk, taps, w, b, **kw)
-    assert_mosaic("biosignal ring kernel", pipeline_ring_pallas,
-                  jax.numpy.stack([chunk] * 4), taps, w, b, **kw)
     assert_mosaic("fir kernel", fir_pallas, sig[None, :window],
                   jax.numpy.asarray(taps), interpret=False)
     seg = sig[None, :app.fft_size // 2]
@@ -191,19 +186,6 @@ def biosignal_phase(front, seed: int) -> None:
             np.asarray(out[key]), ref[key],
             err_msg=f"per-upload vs per-batch {key}")
     log("[smoke] biosignal: per-upload == per-batch, bit for bit")
-
-    rcfg = ResidentConfig(ring_depth=4)
-    _, first_s = timed(lambda: stream.process_resident(sig, rcfg))
-    res, steady_r = timed(lambda: stream.process_resident(sig, rcfg))
-    report("biosignal resident loop", first_s - steady_r, steady_r,
-           f"frames={n} (compile_s = first call - steady call)")
-
-    assert sorted(res) == sorted(out), (sorted(res), sorted(out))
-    for key in out:
-        np.testing.assert_array_equal(
-            np.asarray(res[key]), np.asarray(out[key]),
-            err_msg=f"resident vs per-upload {key}")
-    log("[smoke] biosignal: resident == per-upload, bit for bit")
     for frames in ((0, 128), (n - 128, 128)):
         check_biosignal_reference(app, sig, out, window, hop, frames)
     log("[smoke] biosignal: matches the staged jnp reference "

@@ -189,29 +189,3 @@ def tuned_stream_block_frames(name: str, n_frames: int, window: int,
         key, candidate_stream_block_frames(max(per_col, 1), window, hop),
         lambda rb: lambda: run(rb))
 
-
-def candidate_ring_depths(n_batches: int, *,
-                          max_candidates: int = 4) -> list[int]:
-    """Candidate ring depths (chunks per on-device sweep) for the
-    device-resident loop: powers of two up to the batch count — a deeper
-    ring amortizes more sweep overhead but compiles a wider dispatch and
-    pads more tail batches."""
-    pool = {d for d in (1, 2, 4, 8, 16) if d <= max(n_batches, 1)}
-    pool.add(1)
-    return sorted(pool, reverse=True)[:max_candidates]
-
-
-def tuned_ring_depth(name: str, window: int, hop: int, batch_windows: int,
-                     outputs: tuple, dtype: str, drain_interval: int,
-                     n_batches: int, run: Callable[[int], object]) -> int:
-    """Measured ring depth for `serve.resident.ResidentStream`. The cache
-    key carries the full dispatch shape (window, hop, batch_windows,
-    outputs, dtype), the DRAIN INTERVAL (draining every sweep makes
-    shallow rings pay a counter readback more often, so the winner is
-    per-interval), and the batch count (a 4-batch signal cannot justify a
-    16-deep ring). ``run(rd)`` executes one full resident loop at that
-    ring depth."""
-    key = _freeze((name, window, hop, batch_windows, outputs, dtype,
-                   drain_interval, n_batches))
-    return autotune_block_rows(key, candidate_ring_depths(n_batches),
-                               lambda rd: lambda: run(rd))

@@ -6,7 +6,7 @@ feeds every Whisper-style encoder) has exactly the biosignal pipeline's
 shape: framing -> causal FIR (pre-emphasis) -> rFFT -> matmul epilogue.
 So it is FOUR registered stages over the same graph machinery
 (`graph.py:StageGraph`), compiled into the same single-`pallas_call`
-entries with the in-kernel framing, `outputs=` elision and ring grid of
+entries with the in-kernel framing and `outputs=` elision of
 `kernel.py` completely unchanged:
 
     fir (pre-emphasis, taps [1, -preemph])

@@ -4,15 +4,14 @@ This module is the machinery half of the stage-graph layer
 (`stages.py` is the registry half; `docs/STAGE_GRAPHS.md` the authoring
 guide). A `StageGraph` names a chain of registered stages, binds their
 VMEM table operands, and declares the per-frame outputs; the compiler
-assembles them into the SAME three fused entries the hardcoded
+assembles them into the SAME two fused entries the hardcoded
 biosignal kernel used to own:
 
 * `graph_pallas` — pre-framed (R, S) window batches;
 * `graph_stream_pallas` — RAW 1-D signal, overlapping (window, hop)
   frames built in-kernel from a once-staged chunk (the §4.2
-  single-residency overlap reuse);
-* `graph_ring_pallas` — a (ring_depth, span) ring of raw chunks in one
-  call, the dispatch of the device-resident loop (`serve/resident.py`).
+  single-residency overlap reuse); the per-upload loop
+  (`serve/stream.py:_upload_loop`) runs its core once per dispatch.
 
 Invariants (pinned by `tests/test_stage_graph.py` / `tests/test_asr.py`):
 
@@ -22,9 +21,9 @@ Invariants (pinned by `tests/test_stage_graph.py` / `tests/test_asr.py`):
   `kernel.py:pipeline_stream_kernel`): stage once -> FIR (`_fir_stage`)
   -> registered map stages -> one HBM write. For the biosignal graph
   the outputs are bitwise equal to the pre-refactor fused kernel across
-  every (window, hop, outputs, ring_depth).
+  every (window, hop, outputs).
 * **FIR-first / hop-alignment.** Every graph's first stage is a causal
-  k-tap FIR (`stages.Stage` kind ``"fir"``). The stream/ring framing —
+  k-tap FIR (`stages.Stage` kind ``"fir"``). The stream framing —
   the signal as rows of ``hop`` samples, a body row block plus the next
   block as its tail, FIR once over the laid-out chunk, the frame-local
   zero-history head patch of the first ``n_taps - 1`` columns — is keyed
@@ -40,8 +39,8 @@ Invariants (pinned by `tests/test_stage_graph.py` / `tests/test_asr.py`):
   strictly generalizes the old ``outputs != ("filtered",)`` special
   case.
 * **Stable names.** Each entry names its `pallas_call`
-  ``graph_<mode>_kernel.<graph name>`` (`kernel_name`: modes ``frames``,
-  ``stream``, ``ring``), whichever function jits it.
+  ``graph_<mode>_kernel.<graph name>`` (`kernel_name`: modes ``frames``
+  and ``stream``), whichever function jits it.
 * **A frame's width is the graph's rule** (`frame_width`). A graph
   whose stages window the first ``fft_size`` samples of a frame takes
   any ``window >= fft_size``. A graph whose params carry
@@ -78,7 +77,7 @@ __all__ = ["OutputSpec", "StageGraph", "build_graph", "stages_to_run",
            "canonical_graph_outputs", "graph_empty_outputs",
            "register_graph_factory", "get_graph_factory", "default_app",
            "registered_graphs", "graph_pallas", "graph_stream_pallas",
-           "graph_ring_pallas", "stream_frame_count", "frame_width",
+           "stream_frame_count", "frame_width",
            "min_stream_block_frames", "resolve_stream_block_frames",
            "ring_chunk_samples"]
 
@@ -112,8 +111,9 @@ def resolve_stream_block_frames(n_frames: int, window: int, hop: int,
 
 
 def ring_chunk_samples(window: int, hop: int, batch_windows: int) -> int:
-    """Samples per ring slot: one `batch_windows`-frame dispatch's span —
-    the same arithmetic as `serve.stream.BiosignalStream.chunk_samples`."""
+    """Samples one `batch_windows`-frame dispatch spans: the chunk a
+    stream-kernel launch reads (`serve.stream.BiosignalStream.chunk_samples`
+    and the per-upload loop's slices)."""
     return (batch_windows - 1) * hop + window
 
 
@@ -135,7 +135,7 @@ def _fir_body(state, tables, params):
     """The mandatory first stage, shared by every graph (the biosignal
     lowpass and the ASR pre-emphasis are both instances). The compiled
     bodies never call this: the framing machinery inlines `_fir_stage`
-    itself, because the stream/ring schedule (FIR once over the chunk,
+    itself, because the stream schedule (FIR once over the chunk,
     then the frame-local head patch) cannot be expressed as a per-frame
     map. Kept as the semantic reference of what it inlines."""
     return {"filtered": _fir_stage(state["raw"], tables["fir_taps"],
@@ -434,7 +434,7 @@ def _run_graph(graph: StageGraph, filt, tables: dict, outputs: tuple):
 def _graph_body(graph: StageGraph, x, tables: dict, out_refs: dict,
                 outputs: tuple) -> None:
     """One staged (rb, window) frame block -> FIR-first stage chain -> one
-    HBM write. Shared by every grid: framed, stream and ring."""
+    HBM write. Shared by the framed and stream grids."""
     filt = _fir_stage(x, tables[graph.stages[0].operands[0]], graph.n_taps)
     _write_graph_outputs(graph, out_refs,
                          _run_graph(graph, filt, tables, outputs))
@@ -461,8 +461,7 @@ def graph_stream_kernel(*refs, graph: StageGraph, width: int, hop: int,
     over the chunk, frames ``width`` lanes wide (`frame_width`) are cut by
     static hop slices, and the first ``n_taps - 1`` columns are patched
     with frame-local zero history, so the result is bit-identical to
-    running the graph on host-framed windows. Shared verbatim by the
-    (slot, block) ring grid."""
+    running the graph on host-framed windows."""
     n_taps, rb = graph.n_taps, block_frames
     rows = refs[0][...]
     if n_tails:
@@ -497,27 +496,25 @@ def graph_stream_kernel(*refs, graph: StageGraph, width: int, hop: int,
 # ---------------------------------------------------------------------------
 
 def _operand_specs(operands) -> list:
-    """Broadcast VMEM BlockSpecs for the staged tables: the same index_map
-    takes ANY grid rank, so one operand list serves the 1-D framed/stream
-    grids and the 2-D ring grid."""
+    """Broadcast VMEM BlockSpecs for the staged tables (every block reads
+    the whole table)."""
     return [pl.BlockSpec(tuple(op.shape), lambda *_: (0, 0),
                          memory_space=pltpu.VMEM) for op in operands]
 
 
 def _graph_out_shapes_specs(graph: StageGraph, R: int, rb: int, window: int,
-                            dtype, outputs: tuple, index_map=None):
+                            dtype, outputs: tuple):
     """Output ShapeDtypeStructs + BlockSpecs for an R-row result written
     in rb-row blocks, resolved from the graph's `OutputSpec`s (the
     generic `kernel.py:_out_shapes_specs`)."""
     params = dict(graph.params)
     specs = graph.output_specs
-    imap = index_map if index_map is not None else lambda i: (i, 0)
     out_shape, out_specs = [], []
     for o in outputs:
         trail = specs[o].resolve(window, params) or (1,)
         dt = specs[o].np_dtype(dtype)
         out_shape.append(jax.ShapeDtypeStruct((R,) + trail, dt))
-        out_specs.append(pl.BlockSpec((rb,) + trail, imap,
+        out_specs.append(pl.BlockSpec((rb,) + trail, lambda i: (i, 0),
                                       memory_space=pltpu.VMEM))
     return tuple(out_shape), tuple(out_specs)
 
@@ -530,10 +527,10 @@ def _graph_as_output_dict(graph: StageGraph, outs: tuple, outputs: tuple,
 
 
 def kernel_name(mode: str, graph: StageGraph) -> str:
-    """The `pallas_call` name of a graph's ``mode`` entry (``frames``,
-    ``stream`` or ``ring``), e.g. ``graph_stream_kernel.biosignal``. It
-    is the Mosaic kernel's name and a scope of the call's op name in a
-    profiler trace, whatever function jits the entry."""
+    """The `pallas_call` name of a graph's ``mode`` entry (``frames`` or
+    ``stream``), e.g. ``graph_stream_kernel.biosignal``. It is the Mosaic
+    kernel's name and a scope of the call's op name in a profiler trace,
+    whatever function jits the entry."""
     return f"graph_{mode}_kernel.{graph.name}"
 
 
@@ -569,12 +566,11 @@ def graph_frames_call(frames, operands, *, graph: StageGraph,
 
 def _stream_layout(n: int, window: int, hop: int,
                    block_frames: int | None):
-    """Grid arithmetic shared by the stream and ring entries: frames per
-    step ``rb`` (a sublane multiple, so (rb, hop) row blocks tile), the
-    step count, whether a tail block is needed (window > hop), and the
-    hop-sized rows the padded signal must hold — one block more than the
-    steps when a tail is read, since the last step's tail is the block
-    after it."""
+    """Grid arithmetic of the stream entry: frames per step ``rb`` (a
+    sublane multiple, so (rb, hop) row blocks tile), the step count,
+    whether a tail block is needed (window > hop), and the hop-sized rows
+    the padded signal must hold — one block more than the steps when a
+    tail is read, since the last step's tail is the block after it."""
     rb = round_up(resolve_stream_block_frames(n, window, hop, block_frames),
                   SUBLANES)
     n_blocks = -(-n // rb)
@@ -582,13 +578,9 @@ def _stream_layout(n: int, window: int, hop: int,
     return rb, n_blocks, n_tails, (n_blocks + n_tails) * rb
 
 
-def _row_specs(rb: int, hop: int, n_tails: int, ring: bool) -> list:
-    """Body (+ tail) BlockSpecs over the (rows, hop) signal, or the
-    (slot, rows, hop) ring: the tail is the SAME array one block ahead."""
-    if ring:
-        return [pl.BlockSpec((None, rb, hop), lambda r, j, t=t: (r, j + t, 0),
-                             memory_space=pltpu.VMEM)
-                for t in range(1 + n_tails)]
+def _row_specs(rb: int, hop: int, n_tails: int) -> list:
+    """Body (+ tail) BlockSpecs over the (rows, hop) signal: the tail is
+    the SAME array one block ahead."""
     return [pl.BlockSpec((rb, hop), lambda j, t=t: (j + t, 0),
                          memory_space=pltpu.VMEM)
             for t in range(1 + n_tails)]
@@ -624,7 +616,7 @@ def graph_stream_call(signal, operands, *, graph: StageGraph, window: int,
                           hop=hop, block_frames=rb, outputs=outputs,
                           n_tails=n_tails),
         out_shape=out_shape,
-        in_specs=_row_specs(rb, hop, n_tails, ring=False)
+        in_specs=_row_specs(rb, hop, n_tails)
         + _operand_specs(operands),
         out_specs=out_specs,
         grid=(n_blocks,),
@@ -634,57 +626,8 @@ def graph_stream_call(signal, operands, *, graph: StageGraph, window: int,
     return _graph_as_output_dict(graph, outs, outputs, n)
 
 
-def graph_ring_call(ring, operands, *, graph: StageGraph, window: int,
-                    hop: int, interpret: bool = True,
-                    block_frames: int | None = None, outputs=None):
-    """Unjitted ring core (jit wrapper: `graph_ring_pallas`): a
-    (ring_depth, span) ring of raw chunks through ONE `pallas_call` on a
-    (slot, block) grid, the stream body/tail row blocks reused verbatim
-    per slot. Slot r of the result is bit-identical to
-    `graph_stream_call(ring[r], ...)` — the device-resident loop's
-    dispatch contract."""
-    outputs = canonical_graph_outputs(graph, outputs)
-    D, span = ring.shape
-    width = frame_width(graph, window)
-    assert 0 < hop <= window, (hop, window)
-    n = stream_frame_count(span, window, hop)      # frames per ring slot
-    assert n > 0, f"ring span {span} shorter than one {window}-window"
-    rb, n_blocks, n_tails, rows = _stream_layout(n, width, hop, block_frames)
-    total = rows * hop
-    # pad every slot row to the block tiling (the pad frames are trimmed
-    # per slot)
-    if total > span:
-        ring = jnp.concatenate(
-            [ring, jnp.zeros((D, total - span), ring.dtype)], axis=1)
-    else:
-        ring = ring[:, :total]
-    out_shape, out_specs = _graph_out_shapes_specs(
-        graph, D * n_blocks * rb, rb, window, ring.dtype, outputs,
-        index_map=lambda r, j: (r * n_blocks + j, 0))
-    outs = pl.pallas_call(
-        functools.partial(graph_stream_kernel, graph=graph, width=width,
-                          hop=hop, block_frames=rb, outputs=outputs,
-                          n_tails=n_tails),
-        out_shape=out_shape,
-        in_specs=_row_specs(rb, hop, n_tails, ring=True)
-        + _operand_specs(operands),
-        out_specs=out_specs,
-        grid=(D, n_blocks),
-        interpret=interpret,
-        name=kernel_name("ring", graph),
-    )(*((ring.reshape(D, rows, hop),) * (1 + n_tails)), *operands)
-    res = _graph_as_output_dict(graph, outs, outputs, D * n_blocks * rb)
-    # per-slot trim: every slot framed n_blocks*rb rows, keep its n real
-    # frames and restore the (ring_depth, n, ...) slot structure
-    return {key: v.reshape((D, n_blocks * rb) + v.shape[1:])[:, :n]
-            for key, v in res.items()}
-
-
 graph_pallas = functools.partial(jax.jit, static_argnames=(
     "graph", "interpret", "block_rows", "outputs"))(graph_frames_call)
 graph_stream_pallas = functools.partial(jax.jit, static_argnames=(
     "graph", "window", "hop", "interpret", "block_frames",
     "outputs"))(graph_stream_call)
-graph_ring_pallas = functools.partial(jax.jit, static_argnames=(
-    "graph", "window", "hop", "interpret", "block_frames",
-    "outputs"))(graph_ring_call)

@@ -38,18 +38,18 @@ single-residency analogue of the paper's §4.2 overlap reuse. Both entries
 take an `outputs` selection that elides unrequested computation and HBM
 writes.
 
-**As of the stage-graph refactor** the three public entries
-(`pipeline_pallas`, `pipeline_stream_pallas`, `pipeline_ring_pallas`)
-keep their exact signatures but route through the generic graph compiler
-(`graph.py:graph_stream_pallas` and siblings): the biosignal app is the
-first registered `StageGraph` (stages ``fir -> delineate ->
-biosignal_features -> svm``, registered below), and the compiled body is
-**bit-identical** to the frozen legacy bodies this module retains
-(`pipeline_kernel`, `pipeline_stream_kernel`) because it composes the
-same helpers in the same op order — `tests/test_stage_graph.py` pins
-that equality across (window, hop, outputs, ring_depth). The ASR
-front-end (`asr.py`) is the second graph over the same machinery; see
-`docs/STAGE_GRAPHS.md` for authoring more.
+**As of the stage-graph refactor** the two public entries
+(`pipeline_pallas`, `pipeline_stream_pallas`) keep their exact
+signatures but route through the generic graph compiler
+(`graph.py:graph_frames_call`, `graph.py:graph_stream_call`): the
+biosignal app is the first registered `StageGraph` (stages ``fir ->
+delineate -> biosignal_features -> svm``, registered below), and the
+compiled body is **bit-identical** to the frozen legacy bodies this
+module retains (`pipeline_kernel`, `pipeline_stream_kernel`) because it
+composes the same helpers in the same op order —
+`tests/test_stage_graph.py` pins that equality across (window, hop,
+outputs). The ASR front-end (`asr.py`) is the second graph over the same
+machinery; see `docs/STAGE_GRAPHS.md` for authoring more.
 """
 from __future__ import annotations
 
@@ -68,14 +68,12 @@ from repro.core.shuffle import deinterleave
 from repro.kernels.fft.kernel import radix2_stages, twiddle_table
 from repro.kernels.pipeline.graph import (OutputSpec, _fir_stage,
                                           build_graph, graph_frames_call,
-                                          graph_ring_call,
                                           graph_stream_call,
                                           register_graph_factory)
 # the framing arithmetic lives in graph.py now; re-exported here because
 # this module is its historical import location
 from repro.kernels.pipeline.graph import min_stream_block_frames  # noqa: F401
 from repro.kernels.pipeline.graph import resolve_stream_block_frames  # noqa: F401,E501
-from repro.kernels.pipeline.graph import ring_chunk_samples  # noqa: F401
 from repro.kernels.pipeline.graph import stream_frame_count  # noqa: F401
 from repro.kernels.pipeline.stages import register_stage
 
@@ -203,28 +201,24 @@ def _table_operands(taps, w, b, fft_size: int):
                 jnp.asarray(b, jnp.float32).reshape(1, C))
     shapes = ((1, k), (stages, m), (stages, m), (2, m), (F, C),
               (1, C))
-    # broadcast index_map takes *any* grid rank: the same tables serve the
-    # 1-D framed/stream grids and the 2-D ring grid
+    # broadcast index_map: every block reads the whole table
     specs = [pl.BlockSpec(s, lambda *_: (0, 0), memory_space=pltpu.VMEM)
              for s in shapes]
     return operands, specs
 
 
 def _out_shapes_specs(R: int, S: int, F: int, C: int, rb: int, dtype,
-                      outputs: tuple, index_map=None):
+                      outputs: tuple):
     """Output ShapeDtypeStructs + BlockSpecs for an R-row result written in
-    rb-row blocks. ``index_map`` defaults to the 1-D grid's row advance
-    (block i -> rows [i*rb, (i+1)*rb)); the ring entry passes the 2-D
-    (slot, block) -> flat-row map instead."""
+    rb-row blocks (block i -> rows [i*rb, (i+1)*rb))."""
     table = {
         "filtered": (jax.ShapeDtypeStruct((R, S), dtype), (rb, S)),
         "features": (jax.ShapeDtypeStruct((R, F), jnp.float32), (rb, F)),
         "margin": (jax.ShapeDtypeStruct((R, C), jnp.float32), (rb, C)),
         "class": (jax.ShapeDtypeStruct((R, 1), jnp.int32), (rb, 1)),
     }
-    imap = index_map if index_map is not None else lambda i: (i, 0)
     out_shape = tuple(table[o][0] for o in outputs)
-    out_specs = tuple(pl.BlockSpec(table[o][1], imap,
+    out_specs = tuple(pl.BlockSpec(table[o][1], lambda i: (i, 0),
                                    memory_space=pltpu.VMEM) for o in outputs)
     return out_shape, out_specs
 
@@ -430,45 +424,3 @@ def pipeline_stream_pallas(signal, taps, w, b, *, window: int, hop: int,
     return graph_stream_call(signal, operands, graph=graph, window=window,
                              hop=hop, interpret=interpret,
                              block_frames=block_frames, outputs=outputs)
-
-
-# ---------------------------------------------------------------------------
-# Ring-chunk kernel: one pallas_call over a ring of raw-signal chunks
-# ---------------------------------------------------------------------------
-
-# ring_chunk_samples moved to graph.py (re-exported above).
-
-@functools.partial(jax.jit,
-                   static_argnames=("window", "hop", "fft_size", "interpret",
-                                    "block_frames", "outputs"))
-def pipeline_ring_pallas(ring, taps, w, b, *, window: int, hop: int,
-                         fft_size: int = 512, interpret: bool = True,
-                         block_frames: int | None = None,
-                         outputs: tuple = OUTPUTS):
-    """Fused pipeline over a RING of raw-signal chunks in ONE `pallas_call`.
-
-    ``ring`` is `(ring_depth, span)`: each row is one dispatch-sized raw
-    chunk (what `pipeline_stream_pallas` takes one at a time — span =
-    `ring_chunk_samples(window, hop, batch_windows)` for a
-    `batch_windows`-frame slot). The grid is `(ring_depth, n_blocks)`:
-    the first axis advances the ring slot, the second reuses the
-    in-kernel framing index_maps of the single-chunk stream kernel
-    VERBATIM — slot r is laid out as rows of ``hop`` samples, the body
-    BlockSpec `(r, j) -> (r, j, 0)` is row block j of that slot and the
-    tail BlockSpec the block after it, and `graph.py:graph_stream_kernel`
-    is the kernel body unchanged. This is the kernel half of the device-resident
-    streaming loop (`serve/resident.py`): a whole ring of batches
-    advances frame-blocks inside one compiled dispatch, no host round
-    trip between slots.
-
-    Returns the `pipeline_stream_pallas` output dict per slot, stacked:
-    each value has leading shape `(ring_depth, frames_per_slot)` and row r
-    is bit-identical to `pipeline_stream_pallas(ring[r], ...)` — the
-    property `tests/test_resident.py` pins. Compiles the biosignal
-    `StageGraph` via `graph.py:graph_ring_call`.
-    """
-    outputs = canonical_outputs(outputs)
-    graph, operands = _biosignal_graph_operands(taps, w, b, fft_size)
-    return graph_ring_call(ring, operands, graph=graph, window=window,
-                           hop=hop, interpret=interpret,
-                           block_frames=block_frames, outputs=outputs)

@@ -9,10 +9,10 @@ Two entry points share the in-VMEM stage chain:
   signal chunk, so HBM traffic is ~n_samples instead of n_frames*window
   and the host never gathers frames.
 
-The ``graph_pipeline*`` trio is the GENERIC face of the same machinery:
+The ``graph_pipeline*`` pair is the GENERIC face of the same machinery:
 any registered stage graph (`graph.py:register_graph_factory` —
 ``"biosignal"``, ``"asr"``, or one you author per
-`docs/STAGE_GRAPHS.md`) resolved by name, same framed/stream/ring
+`docs/STAGE_GRAPHS.md`) resolved by name, same framed and stream
 entries, autotune keys carrying the graph name so winners never leak
 across graphs.
 """
@@ -22,22 +22,17 @@ import functools
 
 from repro.kernels import interpret_mode
 from repro.kernels.pipeline.graph import (default_app, get_graph_factory,
-                                          graph_pallas, graph_ring_pallas,
-                                          graph_stream_pallas)
+                                          graph_pallas, graph_stream_pallas)
 from repro.kernels.pipeline.kernel import (OUTPUTS, canonical_outputs,
                                            pipeline_pallas,
-                                           pipeline_ring_pallas,
                                            pipeline_stream_pallas,
-                                           ring_chunk_samples,
                                            stream_frame_count)
 from repro.kernels.pipeline.shard import (column_shares, pipeline_sharded,
                                           pipeline_stream_sharded)
 
 __all__ = ["OUTPUTS", "canonical_outputs", "biosignal_pipeline",
-           "biosignal_pipeline_stream", "biosignal_pipeline_ring",
-           "app_pipeline", "app_pipeline_stream", "app_pipeline_ring",
-           "graph_pipeline", "graph_pipeline_stream", "graph_pipeline_ring",
-           "ring_chunk_samples", "default_app"]
+           "biosignal_pipeline_stream", "app_pipeline", "app_pipeline_stream",
+           "graph_pipeline", "graph_pipeline_stream", "default_app"]
 
 
 
@@ -130,22 +125,6 @@ def biosignal_pipeline_stream(signal, taps, w, b, *, window: int, hop: int,
                     block_frames=block_frames, outputs=outputs)
 
 
-def biosignal_pipeline_ring(ring, taps, w, b, *, window: int, hop: int,
-                            fft_size: int = 512,
-                            block_frames: int | None = None,
-                            outputs=None):
-    """Run the pipeline over a `(ring_depth, span)` RING of raw chunks in
-    one fused `pallas_call` — the kernel entry the device-resident loop
-    (`serve/resident.py`) dispatches per sweep. Each ring slot frames
-    in-kernel exactly like `biosignal_pipeline_stream` on that slot's
-    chunk; slot r of the result is bit-identical to the single-chunk call
-    on `ring[r]`. See `docs/ARCHITECTURE.md` (serving control loop)."""
-    outputs = canonical_outputs(outputs)
-    return pipeline_ring_pallas(ring, taps, w, b, window=window, hop=hop,
-                                fft_size=fft_size, interpret=interpret_mode(),
-                                block_frames=block_frames, outputs=outputs)
-
-
 def graph_pipeline(name: str, app, frames, *,
                    block_rows: int | None = None, autotune: bool = False,
                    outputs=None):
@@ -201,20 +180,6 @@ def graph_pipeline_stream(name: str, app, signal, *, window: int, hop: int,
                                block_frames=block_frames, outputs=outputs)
 
 
-def graph_pipeline_ring(name: str, app, ring, *, window: int, hop: int,
-                        block_frames: int | None = None, outputs=None):
-    """Run a registered stage graph over a `(ring_depth, span)` ring of
-    raw chunks in one fused call — the graph-generic
-    `biosignal_pipeline_ring`, dispatched by the device-resident loop
-    (`serve/resident.py`) for any graph."""
-    factory = get_graph_factory(name)
-    graph, operands = factory(app if app is not None
-                              else default_app(name))
-    return graph_ring_pallas(ring, operands, graph=graph, window=window,
-                             hop=hop, interpret=interpret_mode(),
-                             block_frames=block_frames, outputs=outputs)
-
-
 def app_pipeline(app, signal, *, block_rows: int | None = None,
                  autotune: bool = False, outputs=None, n_columns: int = 1,
                  mesh=None):
@@ -239,14 +204,3 @@ def app_pipeline_stream(app, signal, *, window: int, hop: int,
                                      autotune=autotune, outputs=outputs,
                                      n_columns=n_columns, mesh=mesh,
                                      column_weights=column_weights)
-
-
-def app_pipeline_ring(app, ring, *, window: int, hop: int,
-                      block_frames: int | None = None, outputs=None):
-    """Fused ring-of-chunks execution of a `BiosignalApp` (one
-    `pallas_call` per ring sweep — the device-resident loop's dispatch)."""
-    return biosignal_pipeline_ring(ring, app.fir_taps, app.svm_w, app.svm_b,
-                                   window=window, hop=hop,
-                                   fft_size=app.fft_size,
-                                   block_frames=block_frames,
-                                   outputs=outputs)

@@ -6,15 +6,15 @@ analogue is this registry: a *stage* is one fused-kernel building block
 (FIR, delineation, windowing, packed rFFT, a matmul epilogue, ...) with
 a declared VMEM operand signature, and a *stage graph*
 (`graph.py:StageGraph`) chains registered stages into ONE `pallas_call`
-body — single VMEM residency, in-kernel framing, `outputs=` elision and
-the ring grid all shared across workloads. The biosignal app
+body — single VMEM residency, in-kernel framing and `outputs=` elision
+all shared across workloads. The biosignal app
 (`kernel.py`) and the streaming ASR front-end (`asr.py`) are two graphs
 over this one registry; `docs/STAGE_GRAPHS.md` is the authoring guide.
 
 A stage declares four things:
 
 * ``kind`` — ``"fir"`` for the mandatory FIRST stage (a causal k-tap
-  FIR; the stream/ring framing machinery keys its frame-local head
+  FIR; the stream framing machinery keys its frame-local head
   patch off this stage's tap count), ``"map"`` for everything else;
 * ``operands`` — the names of the staged VMEM table operands its body
   reads (FIR taps, twiddles, Hann window, mel weights, the odd-even

@@ -595,10 +595,8 @@ class ColumnScheduler:
     ITSELF once N windows have retired fleet-wide since the last pass,
     instead of a host-side poller calling it on a timer. The trigger
     consumes whatever the telemetry sees: per-batch retires from the
-    host-driven path or counter DRAINS from the device-resident loop
-    (`serve.resident.ResidentStream` — each drain reports the windows
-    retired on-device since the previous drain), so moving the steady
-    state on-device keeps the closed loop closed. Triggered moves queue
+    host-driven path or one retire per upload from the per-upload loop
+    (`serve.stream.BiosignalStream.process`). Triggered moves queue
     in ``pending_moves``; drain them with `pop_moves` and apply via
     `BiosignalStream.repin`. See `docs/ARCHITECTURE.md`
     (serving-runtime control loop).
@@ -607,8 +605,8 @@ class ColumnScheduler:
     (seconds) and/or a ``straggler`` (`runtime.fault.StragglerDetector`)
     and the scheduler watches column LIVENESS through the same retire
     feed — every retire from a placed stream beats the column's
-    `runtime.fault.HeartbeatMonitor` (resident counter drains included),
-    per-dispatch wall times go in via `record_batch_time`, and
+    `runtime.fault.HeartbeatMonitor`, per-dispatch wall times go in via
+    `record_batch_time`, and
     `supervise` declares a column dead on heartbeat timeout or straggler
     eviction, draining its streams onto survivors (`mark_dead`) and
     zeroing it out of `deal_weights`. The last column dying raises the
@@ -647,8 +645,8 @@ class ColumnScheduler:
         self._load = [0] * len(self.devices)
         self._placement: dict = {}
         # SUPERVISION state: the retire feed doubles as the heartbeat
-        # source (a column that retires work is alive — per-batch retires
-        # and resident counter drains both count), per-column batch
+        # source (a column that retires work is alive — per-batch and
+        # per-upload retires both count), per-column batch
         # times feed the straggler detector, and `supervise` turns both
         # into dead-column declarations + stream drains.
         self._clock = clock
@@ -808,10 +806,9 @@ class ColumnScheduler:
     def _beat_on_retire(self, stream_id, n_windows: int) -> None:
         """Telemetry retire listener: a retire from one of THIS
         scheduler's streams is a heartbeat for its column — per-batch
-        retires (`serve.stream.BiosignalStream._collect`) and resident
-        counter drains (`serve.resident.ResidentStream._drain`) both
-        land here, so moving the steady state on-device keeps the
-        liveness signal alive."""
+        retires (`serve.stream.BiosignalStream._collect`) and per-upload
+        retires (`serve.stream.BiosignalStream._process_upload`) both
+        land here."""
         if stream_id in self._placement:
             self.heartbeats.beat(self._placement[stream_id], self._clock())
 

@@ -6,11 +6,11 @@ INTERCHANGEABLE — and interchangeable must include "one of them died
 mid-stream". This module connects the pure decision logic in
 `runtime/fault.py` (heartbeat timeout, straggler eviction, capped-backoff
 retry) to the live streaming runtime (`serve/stream.py`,
-`serve/resident.py`, `serve/engine.py:ColumnScheduler`):
+`serve/engine.py:ColumnScheduler`):
 
 * the telemetry retire feed doubles as the HEARTBEAT source — every
-  per-batch retire and every resident counter drain beats the column's
-  `runtime.fault.HeartbeatMonitor` (no separate liveness channel);
+  retire beats the column's `runtime.fault.HeartbeatMonitor` (no
+  separate liveness channel);
 * per-column dispatch wall times feed `runtime.fault.StragglerDetector`,
   so a column that is persistently slow gets evicted BEFORE it fails;
 * a dead column's streams DRAIN onto survivors
@@ -25,9 +25,9 @@ retry) to the live streaming runtime (`serve/stream.py`,
 
 THE INVARIANT (the chaos property `tests/test_chaos.py` sweeps): for any
 injected fault schedule — column deaths at arbitrary dispatch steps,
-death mid-resident-sweep, transient faults, stragglers, hangs — the
-recovered output is **bit-identical** to the fault-free run, just
-redistributed across surviving columns. That holds because every unit of
+transient faults, stragglers, hangs — the recovered output is
+**bit-identical** to the fault-free run, just redistributed across
+surviving columns. That holds because every unit of
 requeued work is a HOP-ALIGNED frame range (frame i depends only on
 samples ``[i*hop, i*hop + window)``; the chunk FIR's frame-local
 transient patch makes each frame independent of where the signal is
@@ -35,9 +35,9 @@ cut — the same two facts that make the multi-column deal numerically
 invisible, see `kernels/pipeline/shard.py`).
 
 `FaultInjector` is the chaos harness: a deterministic fault schedule
-keyed by (column, per-column dispatch/drain sequence number), injectable
-into `serve/stream.py:BiosignalStream._dispatch_chunk` and the resident
-drain path (`serve/resident.py:ResidentStream._drain`). The bench gate
+keyed by (column, per-column dispatch sequence number), injectable
+into `serve/stream.py:BiosignalStream._dispatch_chunk` and the per-upload
+loop (`serve/stream.py:BiosignalStream._process_upload`). The bench gate
 (`run.py --check-fault`, `docs/BENCHMARKS.md`) pins the recovery cost:
 killing one of D=4 columns mid-run must keep the modelled dispatch wall
 within 1.5x of the fault-free run, outputs bit-identical.
@@ -66,7 +66,6 @@ from repro.kernels.pipeline.shard import column_shares, requeue_ranges
 from repro.runtime.fault import (ColumnDeadError, StragglerDetector,
                                  Supervisor, TransientDispatchError)
 from repro.serve.engine import ColumnScheduler
-from repro.serve.resident import ResidentConfig, ResidentStream
 from repro.serve.stream import (BiosignalStream, StreamConfig,
                                 StreamTelemetry, frame_count)
 
@@ -103,17 +102,11 @@ class FaultInjector:
     Faults are keyed by ``(column, seq)`` where ``seq`` is the
     per-column DISPATCH sequence number (0-based, incremented on every
     `on_dispatch` call — retried attempts count, so "two transient
-    failures then success" is entries at seq s and s+1). Drain faults
-    use the separate per-column DRAIN counter (`on_drain`, one tick per
-    telemetry drain point of the resident path).
+    failures then success" is entries at seq s and s+1).
 
     * ``kill[column] = seq`` — the dispatch raises
       `runtime.fault.ColumnDeadError` (fatal; the serving layer drains
       and requeues).
-    * ``kill_drain[column] = seq`` — the column dies at that counter
-      DRAIN instead: the resident loop's outputs are lost with the
-      column, but earlier drains already fed the telemetry — the "death
-      mid-resident-sweep" scenario.
     * ``transient`` — set of ``(column, seq)`` dispatches that raise
       `runtime.fault.TransientDispatchError` (retryable; the stream's
       `runtime.fault.Supervisor.call` backoff absorbs them).
@@ -131,18 +124,15 @@ class FaultInjector:
     across bench reps.
     """
     kill: dict = dataclasses.field(default_factory=dict)
-    kill_drain: dict = dataclasses.field(default_factory=dict)
     transient: set = dataclasses.field(default_factory=set)
     hang_from: dict = dataclasses.field(default_factory=dict)
     slow: dict = dataclasses.field(default_factory=dict)
     dispatch_s: float = 0.0
     clock: VirtualClock | None = None
     _seq: dict = dataclasses.field(default_factory=dict)
-    _drain_seq: dict = dataclasses.field(default_factory=dict)
 
     def reset(self) -> None:
         self._seq.clear()
-        self._drain_seq.clear()
 
     def on_dispatch(self, column: int) -> None:
         seq = self._seq.get(column, 0)
@@ -158,13 +148,6 @@ class FaultInjector:
             raise TransientDispatchError(
                 f"injected transient fault on column {column} seq {seq}")
 
-    def on_drain(self, column: int) -> None:
-        seq = self._drain_seq.get(column, 0)
-        self._drain_seq[column] = seq + 1
-        if self.kill_drain.get(column) == seq:
-            raise ColumnDeadError(
-                column, f"column {column} died at drain {seq}")
-
 
 class FaultTolerantColumnRunner:
     """Drives ONE signal across D columns with fault-tolerant requeue —
@@ -173,11 +156,9 @@ class FaultTolerantColumnRunner:
 
     The signal's frames are dealt into hop-aligned per-column ranges
     (`column_shares` exact-sum equal deal, or ``weights``), each range
-    dispatched through the column's pinned stream — a
-    `serve.stream.BiosignalStream` per range of ``cfg.batch_windows``
-    frames (``mode="batch"``), or a `serve.resident.ResidentStream`
-    covering the whole share in ring sweeps (``mode="resident"``). After
-    every dispatch round `ColumnScheduler.supervise` runs: a column is
+    dispatched through the column's pinned `serve.stream.BiosignalStream`,
+    one range of ``cfg.batch_windows`` frames at a time. After every
+    dispatch round `ColumnScheduler.supervise` runs: a column is
     declared dead on `runtime.fault.ColumnDeadError`, heartbeat timeout
     (the retire feed went quiet), or straggler eviction; its streams
     drain and its UNRETIRED ranges requeue across survivors via
@@ -196,7 +177,6 @@ class FaultTolerantColumnRunner:
 
     def __init__(self, app: BiosignalApp | None = None,
                  cfg: StreamConfig | None = None, *, n_columns: int,
-                 mode: str = "batch", rcfg: ResidentConfig | None = None,
                  injector: FaultInjector | None = None,
                  weights=None, deal_band: float = 0.0,
                  heartbeat_timeout: float | None = None,
@@ -204,13 +184,10 @@ class FaultTolerantColumnRunner:
                  retry: Supervisor | None = None, devices=None, clock=None,
                  max_idle_passes: int = 10_000):
         assert n_columns >= 1, n_columns
-        assert mode in ("batch", "resident"), mode
         self.app = app or make_app()
         self.cfg = cfg or StreamConfig()
         assert self.cfg.n_columns == 1, \
             "the runner deals ranges itself; streams stay column-pinned"
-        self.mode = mode
-        self.rcfg = rcfg or ResidentConfig()
         self.injector = injector
         self.weights = weights
         self.deal_band = deal_band
@@ -233,13 +210,9 @@ class FaultTolerantColumnRunner:
         for d in range(n_columns):
             sid = f"col{d}"
             device = self.scheduler.admit(sid)
-            common = dict(telemetry=self.telemetry, stream_id=sid,
-                          column=d, injector=injector, retry=retry)
-            self.streams[d] = (
-                BiosignalStream(self.app, self.cfg, device=device, **common)
-                if mode == "batch" else
-                ResidentStream(self.app, self.cfg, self.rcfg,
-                               device=device, **common))
+            self.streams[d] = BiosignalStream(
+                self.app, self.cfg, device=device, telemetry=self.telemetry,
+                stream_id=sid, column=d, injector=injector, retry=retry)
         self.column_busy = [0.0] * n_columns
         self.dispatches = 0
         self.requeues = 0
@@ -254,10 +227,9 @@ class FaultTolerantColumnRunner:
     # ------------------------------------------------------------ deal
 
     def _initial_queues(self, n_frames: int) -> list[deque]:
-        """Deal frames into per-column queues of hop-aligned ranges:
-        batch mode splits a column's contiguous share into
-        ``batch_windows``-frame dispatch ranges; resident mode keeps the
-        share whole (the ring loop iterates it on-device)."""
+        """Deal frames into per-column queues of hop-aligned ranges: a
+        column's contiguous share splits into ``batch_windows``-frame
+        dispatch ranges."""
         w = self.weights if self.weights is not None \
             else (1.0,) * self.n_columns
         shares = column_shares(n_frames, self.n_columns, w)
@@ -265,12 +237,8 @@ class FaultTolerantColumnRunner:
         start = 0
         bw = self.cfg.batch_windows
         for d, share in enumerate(shares):
-            if self.mode == "resident":
-                if share:
-                    queues[d].append((start, share))
-            else:
-                for s in range(start, start + share, bw):
-                    queues[d].append((s, min(bw, start + share - s)))
+            for s in range(start, start + share, bw):
+                queues[d].append((s, min(bw, start + share - s)))
             start += share
         return queues
 

@@ -51,14 +51,7 @@ stream kernel a per-batch dispatch launches. The host pays one upload,
 one launch and one wait per upload instead of a Python round trip per
 batch. `stream()`, `framing="host"` and ``n_columns > 1`` keep the
 host-driven per-batch loop (`_batches`), the REFERENCE path.
-
-DEVICE-RESIDENT MODE: the steady-state sibling lives in
-`serve/resident.py` (`ResidentStream`, reachable from here via
-`BiosignalStream.process_resident`): a `lax.scan` iterates ring sweeps of
-the donated signal buffer inside one compiled computation and drains the
-retire counters into the same `StreamTelemetry` at a low, configurable
-frequency. Outputs are bit-identical to this path.
-`docs/ARCHITECTURE.md` shows the control loops side by side.
+`docs/ARCHITECTURE.md` shows the two control loops side by side.
 """
 from __future__ import annotations
 
@@ -93,10 +86,8 @@ from repro.serve.trace import span
 
 @dataclasses.dataclass(frozen=True)
 class StreamConfig:
-    """Shape + policy of one stream's dispatches (shared verbatim by the
-    host-driven `BiosignalStream` and the device-resident
-    `serve.resident.ResidentStream`; the resident loop's own knobs live
-    in `serve.resident.ResidentConfig`).
+    """Shape + policy of one stream's dispatches (`BiosignalStream`'s
+    per-upload and per-batch loops read the same fields).
 
     Invariants the runtimes check: ``window`` meets the graph's own
     frame rule (`kernels.pipeline.graph.frame_width`: ``window >=
@@ -263,12 +254,11 @@ class StreamTelemetry:
     ``clock`` is injectable (defaults to `time.perf_counter`) so tests
     and benchmarks can replay measured timings deterministically.
 
-    Retires arrive from BOTH serving modes: the host-driven path reports
-    one per batch (`BiosignalStream._collect`), the device-resident path
-    one per counter drain (`serve.resident.ResidentStream._drain` — the
-    windows retired since the previous drain, so totals match the
-    per-batch accounting exactly). ``add_retire_listener`` lets a
-    consumer observe every retire as it lands — that is how
+    Retires arrive from both loops: the host-driven path reports one per
+    batch (`BiosignalStream._collect`), the per-upload loop one per upload
+    (`BiosignalStream._process_upload`, all its frames), so the totals
+    match. ``add_retire_listener`` lets a consumer observe every retire
+    as it lands — that is how
     `serve.engine.ColumnScheduler`'s retire-count rebalance trigger
     replaces a host-side poller.
     """
@@ -317,8 +307,7 @@ class StreamTelemetry:
     def record_retire(self, stream_id, n_windows: int) -> None:
         """Fold one retired batch (``n_windows`` valid frames) into the
         stream's and its column's EWMAs, then notify retire listeners.
-        In resident mode a "batch" is one counter drain — the delta since
-        the previous drain."""
+        For the per-upload loop a "batch" is the whole upload."""
         if stream_id not in self._stream_col:
             self.attach(stream_id)
         t = self._clock()
@@ -395,10 +384,9 @@ class BiosignalStream:
     `StreamConfig` dispatch shape (see its invariants). Guarantees:
     `process` equals running the fused kernel on
     `frame_signal(signal, window, hop)` in one call — bit-identical
-    across framing modes, column counts, batch sizes, AND the
-    device-resident mode (`process_resident`); the zero-frame degenerate
-    path returns the same keys/dtypes as the hot path. The control-loop
-    structure (what runs on host vs device) is diagrammed in
+    across framing modes, column counts and batch sizes; the zero-frame
+    degenerate path returns the same keys/dtypes as the hot path. The
+    control-loop structure (what runs on host vs device) is diagrammed in
     `docs/ARCHITECTURE.md`, with the ``serve.*`` profiler spans this loop
     records (Tracing). `docs/BENCHMARKS.md` holds CPU interpret-mode
     gates, which time no chip; chip numbers are in `PERF.md`.
@@ -451,7 +439,6 @@ class BiosignalStream:
         self.telemetry = telemetry
         self.stream_id = stream_id if stream_id is not None else id(self)
         self.column = column
-        self._resident = None       # lazy ResidentStream sibling (cached)
         # fault hooks: ``injector`` (a `serve.fault.FaultInjector`) is
         # consulted before every raw-chunk dispatch and may raise
         # TransientDispatchError (retried below) or ColumnDeadError
@@ -492,8 +479,8 @@ class BiosignalStream:
     @property
     def chunk_samples(self) -> int:
         """Raw samples per kernel-framed dispatch: one batch's span."""
-        cfg = self.cfg
-        return (self.dispatch_windows - 1) * cfg.hop + cfg.window
+        return ring_chunk_samples(self.cfg.window, self.cfg.hop,
+                                  self.dispatch_windows)
 
     def _place(self, x):
         return x if self.device is None else jax.device_put(x, self.device)
@@ -708,24 +695,3 @@ class BiosignalStream:
             with span("concat", stream=self.stream_id):
                 return {k: jnp.concatenate([c[k] for c in chunks], axis=0)
                         for k in chunks[0]}
-
-    def process_resident(self, signal, rcfg=None) -> dict:
-        """`process`, but with the steady-state loop ON-DEVICE: delegates
-        to a cached `serve.resident.ResidentStream` sharing this stream's
-        app, config, column pin, telemetry, and stream_id. Outputs are
-        bit-identical to `process`; telemetry sees counter drains (every
-        ``rcfg.drain_interval`` ring sweeps) instead of per-batch
-        retires. ``rcfg`` is a `serve.resident.ResidentConfig` (default:
-        its defaults). Only valid for single-column streams — the same
-        constraint the resident loop asserts."""
-        from repro.serve.resident import ResidentConfig, ResidentStream
-
-        rcfg = rcfg or ResidentConfig()
-        if self._resident is None or self._resident.rcfg != rcfg or \
-                self._resident.device is not self.device:
-            self._resident = ResidentStream(
-                self.app, self.cfg, rcfg, device=self.device,
-                telemetry=self.telemetry, stream_id=self.stream_id,
-                column=self.column, injector=self.injector,
-                retry=self._retry, tables=self._tables)
-        return self._resident.process(signal)

@@ -5,8 +5,7 @@ twiddles, slaney mel matmul) to scale-relative f32 tolerance on every
 (window, hop, n_samples) shape — dividing and non-dividing hops,
 window > fft_size, single-frame, zero-frame, and tail-pad — and the
 graph must ride the shared machinery exactly like the biosignal graph:
-ring slots bit-identical to single-chunk streams, `outputs=` elision
-bit-identical to the full run, the serving runtime
+`outputs=` elision bit-identical to the full run, the serving runtime
 (`serve/stream.py:StreamConfig(graph="asr")`) equal to the one-call
 kernel, and graph-scoped autotune keys."""
 import numpy as np
@@ -17,10 +16,8 @@ from repro.kernels.pipeline.asr import (AsrFrontendApp, asr_reference,
                                         asr_reference_frames, asr_staged,
                                         hann_window, host_frames,
                                         make_asr_frontend, mel_filterbank)
-from repro.kernels.pipeline.graph import (ring_chunk_samples,
-                                          stream_frame_count)
+from repro.kernels.pipeline.graph import stream_frame_count
 from repro.kernels.pipeline.ops import (default_app, graph_pipeline,
-                                        graph_pipeline_ring,
                                         graph_pipeline_stream)
 from repro.serve.stream import BiosignalStream, StreamConfig
 
@@ -103,22 +100,6 @@ def test_block_frames_tile_without_seams(block_frames):
     out = graph_pipeline_stream("asr", app, raw, window=512, hop=160,
                                 block_frames=block_frames)
     _assert_close(out, asr_reference(app, raw, window=512, hop=160))
-
-
-def test_ring_slots_bit_identical_to_stream():
-    """The device-resident dispatch contract, graph-generic: ring slot r
-    == the single-chunk stream on ring[r], BITWISE."""
-    window, hop, bw, depth = 512, 160, 6, 3
-    span = ring_chunk_samples(window, hop, bw)
-    app = make_asr_frontend()
-    ring = np.stack([_audio(span, seed=20 + r) for r in range(depth)])
-    out = graph_pipeline_ring("asr", app, ring, window=window, hop=hop)
-    for r in range(depth):
-        ref = graph_pipeline_stream("asr", app, ring[r], window=window,
-                                    hop=hop)
-        for k in ref:
-            np.testing.assert_array_equal(np.asarray(out[k][r]),
-                                          np.asarray(ref[k]), err_msg=k)
 
 
 def test_outputs_elision_bit_identical():
@@ -217,20 +198,6 @@ def test_asr_graph_is_single_column():
     with pytest.raises(AssertionError, match="single-column"):
         BiosignalStream(None, StreamConfig(window=512, hop=160,
                                            graph="asr", n_columns=2))
-
-
-def test_resident_loop_serves_asr_graph():
-    """`process_resident` (the on-device steady-state loop) stays
-    bit-identical to the host-driven path for the second graph too."""
-    app = make_asr_frontend()
-    raw = _audio(512 * 8, seed=19)
-    cfg = StreamConfig(window=512, hop=256, batch_windows=4, graph="asr")
-    stream = BiosignalStream(app, cfg)
-    host = stream.process(raw)
-    res = stream.process_resident(raw)
-    for k in host:
-        np.testing.assert_array_equal(np.asarray(res[k]),
-                                      np.asarray(host[k]), err_msg=k)
 
 
 def test_autotune_key_is_graph_scoped(tmp_path):

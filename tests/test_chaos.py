@@ -1,9 +1,9 @@
 """Chaos tests for fault-tolerant column serving (`serve/fault.py`).
 
 THE INVARIANT under test everywhere: for ANY injected fault schedule —
-column deaths at arbitrary dispatch steps, death mid-resident-sweep,
-transient dispatch faults, stragglers, hangs — the recovered output is
-**bit-identical** to the fault-free single-column run; only the work
+column deaths at arbitrary dispatch steps, transient dispatch faults,
+stragglers, hangs — the recovered output is **bit-identical** to the
+fault-free single-column run; only the work
 distribution changes. Every scenario runs on the injected `VirtualClock`
 so heartbeat timeouts, EWMA rates, and straggler medians replay
 deterministically.
@@ -19,7 +19,6 @@ from repro.runtime.fault import (InsufficientHealthyWorkers,
 from repro.serve.engine import ColumnScheduler
 from repro.serve.fault import (ColumnHungError, FaultInjector,
                                FaultTolerantColumnRunner, VirtualClock)
-from repro.serve.resident import ResidentConfig
 from repro.serve.stream import (BiosignalStream, StreamConfig,
                                 StreamTelemetry)
 
@@ -139,40 +138,6 @@ def test_all_columns_dead_raises_typed_error(app):
     r = _runner(app, 2, inj, clk)
     with pytest.raises(InsufficientHealthyWorkers):
         r.process(_signal(9))
-
-
-# ------------------------------------------------------- resident deaths
-
-@pytest.mark.parametrize("kill_drain", [0, 1])
-def test_death_mid_resident_sweep(app, reference, kill_drain):
-    """A resident column dying at a counter drain: drains before the
-    death already fed telemetry (heartbeats), the sweep's outputs are
-    lost with the column, and the whole share requeues onto survivors."""
-    clk = VirtualClock()
-    inj = FaultInjector(kill_drain={1: kill_drain}, dispatch_s=0.01,
-                        clock=clk)
-    # ring_depth=1 + drain_interval=1: one drain per batch, so the
-    # 4-frame share has two drain points and kill_drain=1 lands AFTER a
-    # drain already fed telemetry
-    r = FaultTolerantColumnRunner(
-        app, CFG, n_columns=3, mode="resident",
-        rcfg=ResidentConfig(ring_depth=1, drain_interval=1),
-        injector=inj, clock=clk)
-    out = r.process(_signal(13))
-    _assert_identical(reference(13), out)
-    assert r.scheduler.dead == {1}
-
-
-def test_resident_fault_free_matches_reference(app, reference):
-    clk = VirtualClock()
-    inj = FaultInjector(dispatch_s=0.01, clock=clk)
-    r = FaultTolerantColumnRunner(
-        app, CFG, n_columns=3, mode="resident",
-        rcfg=ResidentConfig(ring_depth=2, drain_interval=1),
-        injector=inj, clock=clk)
-    out = r.process(_signal(13))
-    _assert_identical(reference(13), out)
-    assert r.scheduler.dead == set()
 
 
 # -------------------------------------------------- hangs and stragglers

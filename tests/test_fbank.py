@@ -90,15 +90,6 @@ def test_upload_loop_is_bit_identical_to_per_batch_loop():
         assert energy <= ENERGY_TOL and log <= LOG_TOL, (energy, log)
 
 
-def test_resident_ring_is_bit_identical_to_upload_loop():
-    """The ring entry frames the fbank graph as the stream entry does."""
-    sig = _audio(160 * 19 + 400 + 77, seed=3)
-    stream = BiosignalStream(APP, _cfg())
-    np.testing.assert_array_equal(
-        np.asarray(stream.process_resident(sig)["fbank"]),
-        np.asarray(stream.process(sig)["fbank"]))
-
-
 def test_served_through_stream_open():
     front = ServeFrontend(scheduler=ColumnScheduler(jax.devices()[:1]))
     ticket = front.submit(StreamOpen(

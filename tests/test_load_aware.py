@@ -409,6 +409,51 @@ def test_open_stream_wires_telemetry_through():
     assert tel.column_load(col) == 0.0      # detached on release
 
 
+def test_retire_trigger_feeds_on_upload_retires():
+    """The scheduler's retire-count trigger consumes the per-upload loop's
+    retires exactly like per-batch retires — no host poller anywhere."""
+    clock = VirtualClock()
+    tel = StreamTelemetry(clock=clock)
+    sched = ColumnScheduler(devices=[None], telemetry=tel,
+                            rebalance_every=10 ** 9)
+    device = sched.admit("up-stream")
+    assert device is None                   # the placeholder column
+    app = make_app()
+    cfg = StreamConfig(window=512, hop=256, batch_windows=4)
+    sig, _ = synthetic_respiration(1, 256 * 24 + 512, seed=5)
+    stream = BiosignalStream(app, cfg, device=device, telemetry=tel,
+                             stream_id="up-stream")
+    stream.process(sig[0])
+    n = frame_count(sig.shape[1], 512, 256)
+    assert sched._retired_since_rebalance == n
+
+
+def test_retire_trigger_rebalances_and_queues_moves():
+    clock = VirtualClock()
+    tel = StreamTelemetry(clock=clock)
+    sched = ColumnScheduler(devices=["d0", "d1"], telemetry=tel,
+                            rebalance_every=60)
+    for sid in ("s1", "s2", "s3"):
+        sched.admit(sid)                    # round-robin: s1,s3 -> col0
+    assert sched.column_of("s3") == 0
+    # warm the rates: s1 and s3 are heavy (10 windows per tick), s2 light
+    for _ in range(6):
+        clock.advance(1.0)
+        tel.record_retire("s1", 10)
+        tel.record_retire("s3", 10)
+        tel.record_retire("s2", 1)
+    # the trigger fired mid-loop (>= 60 windows retired) and queued the
+    # work-stealing move off the overloaded column 0
+    moves = sched.pop_moves()
+    assert moves, "retire-count trigger never rebalanced"
+    assert set(moves.values()) <= {"d0", "d1"}
+    assert sched.pop_moves() == {}          # drained
+    # a foreign stream sharing the telemetry never counts
+    before = sched._retired_since_rebalance
+    tel.record_retire("not-mine", 500)
+    assert sched._retired_since_rebalance == before
+
+
 # ----------------------------------------------------- trajectory + gate
 
 def _bench_json(path, rows):
@@ -496,3 +541,19 @@ def test_diff_autotune_missing_baseline_is_loud(tmp_path):
                   "--missing-baseline-ok")
     assert r.returncode == 1
     assert "REGRESSED" in r.stdout
+
+
+def test_diff_autotune_retired_record_is_reported_not_failed():
+    """A pinned record whose code was deleted on purpose (``RETIRED``) is
+    reported when the baseline still holds it; any other record that
+    disappears keeps failing the gate."""
+    from benchmarks.diff_autotune import RETIRED, gate_pinned
+
+    kept = {"us": 100.0, "ratio": 1.4, "spread": 0.01}
+    retired = next(iter(RETIRED))
+    report, failures = gate_pinned({"p": kept, retired: kept}, {"p": kept})
+    assert failures == []
+    assert any(retired in line and "retired" in line for line in report)
+    report, failures = gate_pinned({"p": kept, "gone": kept}, {"p": kept})
+    assert len(failures) == 1 and "gone" in failures[0]
+    assert "DISAPPEARED" in failures[0]

@@ -9,7 +9,7 @@ the frozen legacy bodies `kernel.py:pipeline_kernel` /
 `kernel.py:pipeline_stream_kernel`, which this module keeps alive by
 rebuilding the pre-refactor `pallas_call` from them verbatim and
 comparing with `np.testing.assert_array_equal` (not allclose) across
-(window, hop, outputs, ring_depth). The second half pins the
+(window, hop, outputs). The second half pins the
 `stages.py` error taxonomy and exercises the authoring path end to end
 with a brand-new throwaway graph — the `docs/STAGE_GRAPHS.md` recipe.
 """
@@ -33,11 +33,9 @@ from repro.kernels.pipeline.kernel import (OUTPUTS, _as_output_dict,
                                            canonical_outputs, empty_outputs,
                                            min_stream_block_frames,
                                            pipeline_kernel, pipeline_pallas,
-                                           pipeline_ring_pallas,
                                            pipeline_stream_kernel,
                                            pipeline_stream_pallas,
                                            resolve_stream_block_frames,
-                                           ring_chunk_samples,
                                            stream_frame_count)
 
 
@@ -178,23 +176,6 @@ def test_framed_bit_identical_to_legacy_kernel(outputs):
     ref = _legacy_frames(sig, app.fir_taps, app.svm_w, app.svm_b,
                          outputs=sel)
     _assert_bitwise(out, ref)
-
-
-@pytest.mark.parametrize("ring_depth", [1, 3])
-def test_ring_bit_identical_to_legacy_per_slot(ring_depth):
-    """The (slot, block) ring grid vs the legacy single-chunk kernel run
-    slot by slot — the `ring_depth` leg of the bit-identity sweep."""
-    window, hop, bw = 512, 128, 6
-    span = ring_chunk_samples(window, hop, bw)
-    app = make_app()
-    ring = np.stack([np.asarray(_raw(span, seed=40 + r))
-                     for r in range(ring_depth)])
-    out = pipeline_ring_pallas(jnp.asarray(ring), app.fir_taps, app.svm_w,
-                               app.svm_b, window=window, hop=hop)
-    for r in range(ring_depth):
-        ref = _legacy_stream(ring[r], app.fir_taps, app.svm_w, app.svm_b,
-                             window=window, hop=hop)
-        _assert_bitwise({k: v[r] for k, v in out.items()}, ref)
 
 
 def test_zero_frame_path_matches_legacy_empty():

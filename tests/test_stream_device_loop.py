@@ -16,12 +16,14 @@ from repro.core.biosignal import make_app
 from repro.runtime.fault import ColumnDeadError
 from repro.serve.fault import FaultInjector
 from repro.serve.stream import (BiosignalStream, StreamConfig,
-                                StreamTelemetry)
+                                StreamTelemetry, frame_count)
 
 BW = 4
 CFGS = {"biosignal": StreamConfig(window=512, hop=256, batch_windows=BW),
         "asr": StreamConfig(window=512, hop=128, batch_windows=BW,
-                            graph="asr")}
+                            graph="asr"),
+        "fbank": StreamConfig(window=400, hop=160, batch_windows=BW,
+                              graph="fbank")}
 
 
 @pytest.fixture(scope="module")
@@ -36,24 +38,53 @@ def _signal(cfg, n_frames: int, seed: int = 0):
     return np.random.default_rng(seed).normal(size=n).astype(np.float32)
 
 
-def _stream(app, graph, **kw):
-    return BiosignalStream(app if graph == "biosignal" else None,
-                           CFGS[graph], **kw)
+# the biosignal graph at framings other than CFGS's, each cut at an exact
+# sample count: (window, hop, batch_windows, n_samples)
+SHAPES = [
+    (512, 128, 4, 128 * 32 + 512),         # dispatches cover the frames
+    (512, 128, 4, 128 * 29 + 77),          # ragged tail dispatch
+    (512, 512, 3, 512 * 5 + 11),           # hop == window, no tail rows
+    (1024, 320, 2, 320 * 9 + 1024 + 5),    # hop does not divide window
+    (2048, 512, 8, 512 * 40 + 2048),       # the archive cell's shape
+    (512, 256, 4, 256 * 2 + 512),          # fewer frames than a dispatch
+]
 
 
-@pytest.mark.parametrize("pinned", [False, True])
-@pytest.mark.parametrize("n_frames", [0, 1, BW - 1, BW, BW + 1, 3 * BW + 5])
-@pytest.mark.parametrize("graph", sorted(CFGS))
-def test_process_equals_the_per_batch_loop(app, graph, n_frames, pinned):
+def _loop_cases():
+    """(graph, cfg, signal, pinned): every CFGS graph at tails around one
+    dispatch, pinned and not, then each of SHAPES."""
+    for graph in sorted(CFGS):
+        for n_frames in (0, 1, BW - 1, BW, BW + 1, 3 * BW + 5):
+            for pinned in (False, True):
+                yield pytest.param(
+                    graph, CFGS[graph],
+                    _signal(CFGS[graph], n_frames, seed=n_frames), pinned,
+                    id=f"{graph}-{n_frames}-{pinned}")
+    for window, hop, bw, n_samples in SHAPES:
+        sig = np.random.default_rng(hop + bw).normal(size=n_samples) \
+            .astype(np.float32)
+        yield pytest.param(
+            "biosignal", StreamConfig(window=window, hop=hop,
+                                      batch_windows=bw), sig, False,
+            id=f"{window}-{hop}-{bw}-{n_samples}")
+
+
+@pytest.mark.parametrize("graph,cfg,sig,pinned", _loop_cases())
+def test_process_equals_the_per_batch_loop(app, graph, cfg, sig, pinned):
     device = jax.devices()[0] if pinned else None
-    sig = _signal(CFGS[graph], n_frames, seed=n_frames)
+    n_frames = frame_count(sig.shape[0], cfg.window, cfg.hop)
     loop_tel, batch_tel = StreamTelemetry(), StreamTelemetry()
-    out = _stream(app, graph, device=device, telemetry=loop_tel,
-                  stream_id="s", column=1).process(sig)
-    batches = list(_stream(app, graph, device=device, telemetry=batch_tel,
-                           stream_id="s", column=1).stream(sig))
-    assert len(batches) == -(-n_frames // BW)
-    ref = _stream(app, graph)._empty(np.float32) if not batches else {
+
+    def stream(**kw):
+        return BiosignalStream(app if graph == "biosignal" else None, cfg,
+                               **kw)
+
+    out = stream(device=device, telemetry=loop_tel, stream_id="s",
+                 column=1).process(sig)
+    batches = list(stream(device=device, telemetry=batch_tel,
+                          stream_id="s", column=1).stream(sig))
+    assert len(batches) == -(-n_frames // cfg.batch_windows)
+    ref = stream()._empty(np.float32) if not batches else {
         k: np.concatenate([np.asarray(b[k]) for b in batches])
         for k in batches[0]}
     assert out.keys() == ref.keys()
@@ -63,10 +94,48 @@ def test_process_equals_the_per_batch_loop(app, graph, n_frames, pinned):
         np.testing.assert_array_equal(got, np.asarray(ref[k]), err_msg=k)
         assert got.shape[0] == n_frames
     if pinned and n_frames:
-        assert out["features" if graph == "biosignal" else "logmel"] \
-            .devices() == {device}
+        assert out[next(iter(out))].devices() == {device}
     assert loop_tel.column_stats(2)[1].windows == \
         batch_tel.column_stats(2)[1].windows == n_frames
+
+
+def _retires(app, cfg, n_samples, uploads, per_upload):
+    """The retires a stream reports for ``uploads`` successive uploads
+    (each of ``n_samples``), and the windows its column holds after."""
+    tel = StreamTelemetry()
+    retires = []
+    tel.add_retire_listener(lambda sid, nw: retires.append(nw))
+    stream = BiosignalStream(app, cfg, telemetry=tel, stream_id="s",
+                             column=1)
+    for u in range(uploads):
+        sig = np.random.default_rng(u).normal(size=n_samples) \
+            .astype(np.float32)
+        if per_upload:
+            stream.process(sig)
+        else:
+            list(stream.stream(sig))
+    return retires, tel.column_stats(2)[1].windows
+
+
+@pytest.mark.parametrize("uploads", [1, 2, 3, 7])
+@pytest.mark.parametrize("window,hop,bw,n_samples", [
+    (512, 128, 4, 128 * 32 + 512),
+    (512, 256, 3, 256 * 20 + 99),          # ragged tail dispatch
+    (512, 512, 2, 512 * 5),                # exact cover, no pad
+])
+def test_upload_retires_match_per_batch_accounting(app, window, hop, bw,
+                                                   n_samples, uploads):
+    """Successive uploads through one stream: the per-upload loop retires
+    each upload's ``n`` frames once, the per-batch loop dispatch by
+    dispatch, and both land ``uploads * n`` windows on the column, no pad
+    frame counted."""
+    cfg = StreamConfig(window=window, hop=hop, batch_windows=bw)
+    n = frame_count(n_samples, window, hop)
+    loop, loop_windows = _retires(app, cfg, n_samples, uploads, True)
+    batch, batch_windows = _retires(app, cfg, n_samples, uploads, False)
+    assert loop == [n] * uploads
+    assert len(batch) == uploads * -(-n // bw)
+    assert sum(batch) == loop_windows == batch_windows == uploads * n
 
 
 def test_an_autotuned_upload_uses_the_per_batch_block(app):

@@ -25,8 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.fft.kernel import fft_pallas
 from repro.kernels.fir.kernel import fir_pallas
 from repro.kernels.pipeline.graph import (default_app, get_graph_factory,
-                                          graph_pallas, graph_ring_pallas,
-                                          graph_stream_pallas,
+                                          graph_pallas, graph_stream_pallas,
                                           ring_chunk_samples)
 from repro.kernels.rope.kernel import rope_pallas
 from repro.serve.stream import _upload_loop
@@ -81,15 +80,6 @@ def test_graph_stream_compiles(name, one_chip):
     _assert_mosaic(lambda x, *o: graph_stream_pallas(
         x, o, graph=graph, window=window, hop=hop, interpret=False),
         _shape((n_samples,), one_chip), *ops)
-
-
-def test_biosignal_ring_compiles(one_chip):
-    window, hop = GRAPHS["biosignal"]
-    graph, ops = _graph("biosignal", one_chip)
-    span = ring_chunk_samples(window, hop, 64)
-    _assert_mosaic(lambda x, *o: graph_ring_pallas(
-        x, o, graph=graph, window=window, hop=hop, interpret=False),
-        _shape((4, span), one_chip), *ops)
 
 
 def _assert_upload_loop(name, n_samples, outputs, one_chip):

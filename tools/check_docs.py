@@ -12,7 +12,7 @@ link targets in `docs/*.md` and `README.md`):
   resolved against the repo root and, for source paths written without
   the `src/` prefix (e.g. `serve/stream.py`), against `src/repro/`;
   directory references ending in `/` are checked as directories;
-* `path.py:symbol` anchors (e.g. `serve/resident.py:ResidentStream` or
+* `path.py:symbol` anchors (e.g. `serve/stream.py:BiosignalStream` or
   `serve/stream.py:StreamTelemetry.record_retire`) — the file must exist
   AND define the symbol: a `class`/`def` of that name at any nesting, or
   a `name = ...` / `name: ...` binding; dotted `Cls.member` requires the
